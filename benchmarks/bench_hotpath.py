@@ -113,14 +113,13 @@ def _bench_warm_cache(problems: list, policy) -> dict:
 
 def _bench_pivot_roofline(quick: bool) -> dict | None:
     """Time the tuned K-pivot kernel on the chain bucket's tableau shape."""
-    from jax.experimental import enable_x64
-
     from repro.engine.autotune import _probe_stack, cache_snapshot, pivot_schedule
-    from repro.kernels.ops import scheduling_kernels_available, simplex_pivot
+    from repro.jaxenv import x64
+    from repro.kernels.ops import scheduling_kernels_error, simplex_pivot
 
     from .roofline import kernel_roofline
 
-    if not scheduling_kernels_available():
+    if scheduling_kernels_error() is not None:
         return None
     # the chain-mix LP tableau shape (m=3, 2 loads, q=1) as solved by the
     # pallas driver; pivot_schedule memoizes, so a prior pallas solve in
@@ -137,7 +136,7 @@ def _bench_pivot_roofline(quick: bool) -> dict | None:
     status = np.tile(status, reps)[:B]
     kw = dict(ncols_price=C - 1, bland_after=10_000, max_iter=10_000,
               k_pivots=k)
-    with enable_x64():
+    with x64():
         out = simplex_pivot(T, basis, it, status, **kw)  # compile
         out[0].block_until_ready()
         t0 = time.perf_counter()

@@ -46,13 +46,14 @@ def _scheduling_rows(quick: bool) -> list:
     """The engine's own hot loops: one fused pivot over a synthetic tableau
     stack, and the fused ASAP replay of an arena-shaped bucket."""
     rows = []
-    if not ops.scheduling_kernels_available():
-        print("  scheduling kernels unavailable here — skipping their rows")
+    reason = ops.scheduling_kernels_error()
+    if reason is not None:
+        print(f"  scheduling kernels unavailable here ({reason}) — skipping their rows")
         return rows
-    from jax.experimental import enable_x64
+    from repro.jaxenv import x64
 
     key = jax.random.PRNGKey(7)
-    with enable_x64():
+    with x64():
         # simplex_pivot: [B, R, C] stack, rhs kept feasible so the masked
         # pivot does real pricing + elimination work on every element
         B, R, C = (16, 16, 32) if quick else (64, 16, 32)

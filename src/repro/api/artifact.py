@@ -148,7 +148,7 @@ class PlanArtifact:
     n_rows: int
     sweep: dict | None = None  # auto-T provenance: qs/makespans/costs/t_star_index
     # v2: structured provenance events — dicts with at least
-    # {"kind": "fallback"|"degrade"|"serial-rescue"|"rescue"|"error",
+    # {"kind": "fallback"|"serial-rescue"|"rescue"|"error",
     #  "backend": str, "reason": str} (error events add "error_type" and
     #  "error_chain"); supersedes the fallback_events strings (kept as shims)
     events: tuple = ()

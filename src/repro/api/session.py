@@ -822,8 +822,8 @@ class Session:
         base = label[: -len("+cache")] if cache_hit else label
         # "auto"/"serial" delegate by design — any serial label matches them;
         # everything else that changed hands is provenance worth recording
-        # (engine fallback to the serial solver, pallas degrading to batched,
-        # the simplex's scipy rescue, ...)
+        # (engine fallback to the serial solver, the simplex's scipy rescue,
+        # ...)
         telemetry = getattr(report, "telemetry", None)
         if requested in ("auto", "serial") or base == requested:
             legacy: tuple = ()
@@ -831,9 +831,7 @@ class Session:
         else:
             legacy = (f"served_by:{base}",)
             # classify WHY the serving backend differs from the requested one
-            if requested == "pallas" and base in ("batched", "batched+serial"):
-                kind = "degrade"  # fused kernels unavailable/inapplicable here
-            elif requested in _ENGINE_BACKENDS and base in _SERIAL_LABELS:
+            if requested in _ENGINE_BACKENDS and base in _SERIAL_LABELS:
                 kind = "serial-rescue"  # bulk path certified this element serially
             elif base.startswith(requested + "+"):
                 kind = "rescue"  # e.g. simplex+scipy: numerical rescue mid-solve

@@ -19,9 +19,8 @@ Built-in backends:
   "batched"  — the JAX engine (repro.engine.service.BatchedBackend),
                registered lazily so importing repro.core never imports jax;
   "pallas"   — the same engine with its hot loops in fused Pallas kernels
-               (repro.kernels.simplex_pivot / asap_replay); degrades to the
-               plain batched path when the kernels cannot run here, so the
-               entry is always safe to select.
+               (repro.kernels.simplex_pivot / asap_replay); selecting it
+               raises where the kernels cannot run.
 
 Every optimal solve is finished by an ASAP *replay* of the LP's fractions
 through the simulator: the replay is guaranteed feasible, its makespan can
@@ -373,10 +372,6 @@ def _batched_factory(cache=None):
 def _pallas_factory(cache=None):
     from repro.engine.service import PallasBackend  # deferred: jax import
 
-    # PallasBackend itself degrades to the plain batched path when the
-    # fused kernels cannot run here (scheduling_kernels_available probe),
-    # so selecting "pallas" is always safe; statuses and SolveReport
-    # fields are identical either way.
     return PallasBackend(cache=cache)
 
 

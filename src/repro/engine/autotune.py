@@ -73,8 +73,7 @@ def pivot_schedule(
     entry; the first call per shape runs the timed sweep (a handful of tiny
     kernel launches), subsequent calls are a dict hit.
     """
-    from jax.experimental import enable_x64
-
+    from repro.jaxenv import x64
     from repro.kernels.ops import _interp, simplex_pivot
 
     interp = bool(_interp(interpret))
@@ -86,7 +85,7 @@ def pivot_schedule(
     T, basis, it, status = _probe_stack(n_rows, n_cols)
     max_iter = _EPOCH_PIVOTS * 4  # plenty of headroom for the probe
     per_pivot: dict[int, float] = {}
-    with enable_x64():
+    with x64():
         for k in sweep:
             kw = dict(
                 ncols_price=n_cols - 1, bland_after=max_iter,

@@ -34,7 +34,7 @@ The recurrence per cell ``t`` (identical to the NumPy reference):
   whose carry crosses cells); the makespan additionally covers every return
   arrival.
 
-Everything runs in float64 (``jax.experimental.enable_x64``); the operations
+Everything runs in float64 (under :func:`repro.jaxenv.x64`); the operations
 are the same IEEE max/add/mul the NumPy simulator performs, so results match
 it to the last ulp in practice (parity-tested at <= 1e-9).
 
@@ -52,9 +52,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
-
 from repro.core.schedule import Schedule
+from repro.jaxenv import x64
 
 from .arena import InstanceArena, PackedBucket
 
@@ -256,7 +255,7 @@ def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray,
         bucket.vcomm_cell, bucket.vcomp_cell, bucket.rel_cell,
     )
     with_ret = bool(bucket.has_returns) and bucket.m > 1
-    with enable_x64():
+    with x64():
         retr = bucket.ret_cell
         valid = np.asarray(bucket.cell_valid, dtype=np.float64)
         g = np.asarray(gamma, dtype=np.float64)

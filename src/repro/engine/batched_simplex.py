@@ -17,16 +17,18 @@ Fixed-shape reformulation (everything static so ``vmap``/``jit`` apply):
     dummy column (it prices at 0, so it never re-enters).  This keeps the
     tableau ~1/3 the width of the explicit form — the pivot's rank-1 update
     is the memory-bound inner loop, so width is throughput;
-  * each pivot is a *single* fused rank-1 update ``T -= outer(pcol', prow)``
-    where ``pcol'`` carries ``piv - 1`` at the pivot row (this updates the
-    pivot row to ``T[row]/piv`` in the same pass) and is zeroed wholesale to
-    mask finished batch elements;
+  * each pivot is a *single* elementwise pass: every other row subtracts
+    ``pcol * prow`` and the pivot row becomes ``prow = T[row]/piv``
+    (:func:`_fused_pivot`); ``pcol`` is zeroed wholesale to mask finished
+    batch elements;
   * each phase is a ``lax.while_loop`` whose carry holds (tableau, basis,
     iteration, status); JAX's batching rule for ``while_loop`` masks finished
     batch elements automatically;
   * pricing is Dantzig with a Bland fallback after ``max(200, 4 rows)``
-    iterations (anti-cycling), and the ratio test tie-breaks on the smallest
-    basis index — mirroring the NumPy solver's rules.
+    iterations (anti-cycling); the ratio test is Harris's two-pass rule
+    with a relative pivot threshold (:func:`repro.pivoting.harris_row`), which keeps
+    paper-size LPs (thousands of rows and pivots) from cycling or drifting
+    off the polytope.
 
 Statuses are small ints (see STATUS) so they vectorize.
 """
@@ -41,7 +43,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+
+from repro.jaxenv import x64
+from repro.pivoting import harris_row
 
 __all__ = ["BatchedSimplexResult", "solve_simplex_batched", "STATUS"]
 
@@ -112,16 +116,24 @@ def _equilibrate(A, b, c, iters=3):
 def _fused_pivot(T, row, col, do_pivot):
     """One-pass masked pivot: returns T after pivoting on (row, col).
 
-    ``prow = T[row]/piv`` and ``pcol`` holds the entering column with the
-    pivot entry replaced by ``piv - 1``, so ``T - outer(pcol, prow)`` both
-    eliminates the column and rescales the pivot row:
-    ``T[row] - (piv-1) * T[row]/piv = T[row]/piv``.
+    ``prow = T[row]/piv`` becomes the pivot row and every other row
+    subtracts ``T[i, col] * prow``, in one elementwise pass.  The pivot row
+    is written as ``prow`` itself, not folded into the rank-1 update as
+    ``T[row] - (piv-1) * prow``: that form cancels when |piv| is large, and
+    the TPU's emulated float64 loses up to 4.6e-8 relative on it against
+    ~1e-14 for the division alone (measured on a v5e), which over thousands
+    of pivots drifts the tableau off the polytope.  For the same reason the
+    entering column is written as the exact unit vector of the pivot row.
     """
     piv = jnp.where(do_pivot, T[row, col], 1.0)
     prow = T[row] / piv
-    pcol = T[:, col].at[row].set(piv - 1.0)
-    pcol = jnp.where(do_pivot, pcol, 0.0)
-    return T - jnp.outer(pcol, prow)
+    is_row = jnp.arange(T.shape[0]) == row
+    pcol = jnp.where(do_pivot & ~is_row, T[:, col], 0.0)
+    T = jnp.where((do_pivot & is_row)[:, None], prow[None, :],
+                  T - jnp.outer(pcol, prow))
+    is_col = jnp.arange(T.shape[1]) == col
+    return jnp.where(do_pivot & is_col[None, :],
+                     is_row[:, None].astype(T.dtype), T)
 
 
 def _phase(T, basis, ncols_price, max_iter, bland_after):
@@ -140,14 +152,8 @@ def _phase(T, basis, ncols_price, max_iter, bland_after):
         bland = jnp.argmin(jnp.where(neg, jnp.arange(ncols_price), ncols_price))
         col = jnp.where(it < bland_after, dantzig, bland)
 
-        colvals = T[:-1, col]
-        pos = colvals > _EPS
-        ratios = jnp.where(pos, T[:-1, -1] / jnp.where(pos, colvals, 1.0), jnp.inf)
-        best = ratios[jnp.argmin(ratios)]
-        unbounded = ~jnp.isfinite(best)
-        # tie-break on the smallest basis index (same rule as the NumPy solver)
-        ties = jnp.abs(ratios - best) <= 1e-12
-        row = jnp.argmin(jnp.where(ties, basis, jnp.iinfo(jnp.int32).max))
+        row, unbounded = harris_row(T[:-1, col], T[:-1, -1], basis,
+                                    it >= bland_after)
 
         do_pivot = any_neg & ~unbounded
         T = _fused_pivot(T, row, col, do_pivot)
@@ -649,7 +655,7 @@ def solve_simplex_batched(
     # numpy args go straight into the jitted calls (their argument machinery
     # batches host->device transfers; explicit per-array jnp.asarray costs
     # ~100us per array and was a measurable share of small-bucket solves)
-    with enable_x64():
+    with x64():
         x = np.empty((B, n))
         obj = np.empty(B)
         status = np.empty(B, np.int32)

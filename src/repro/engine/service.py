@@ -426,26 +426,27 @@ class PallasBackend(BatchedBackend):
     Same bulk path, cache semantics, certification-by-replay, and serial
     fallback contract as :class:`BatchedBackend` — the simplex pivots and
     the ASAP replay just run in ``repro.kernels.simplex_pivot`` /
-    ``asap_replay`` (interpret-mode on CPU).  Statuses and every
+    ``asap_replay`` (interpret-mode on the CPU).  Statuses and every
     :class:`SolveReport` field behave identically; ``report.backend`` says
-    ``"pallas"``.  When the kernels cannot run here at all (probed once via
-    ``scheduling_kernels_available``) the instance degrades to the plain
-    batched path instead of failing — the registry entry is always safe to
-    select.
+    ``"pallas"``.  Where the kernels cannot run (probed once via
+    ``scheduling_kernels_error``) construction raises with the lowering's or
+    compiler's reason: selecting ``pallas`` never silently runs ``batched``.
     """
 
     name = "pallas"
+    use_pallas = True
 
     def __init__(self, cache: SolutionCache | None = None, fallback: bool = True):
         super().__init__(cache=cache, fallback=fallback)
-        from repro.kernels.ops import scheduling_kernels_available
+        from repro.kernels.ops import scheduling_kernels_error
 
-        self.use_pallas = scheduling_kernels_available()
-        if not self.use_pallas:
-            obs_metrics.get_registry().inc(
-                "repro_engine_pallas_degrade_total",
-                reason="kernels_unavailable",
-            )
+        reason = scheduling_kernels_error()
+        if reason is not None:
+            import jax
+
+            raise RuntimeError(
+                f"the pallas backend cannot run on {jax.default_backend()}: "
+                f"{reason}")
 
 
 @dataclasses.dataclass

@@ -2,13 +2,14 @@
 
 Model code calls these (via ShardingPolicy.attention_impl == "pallas" etc.);
 layout munging (head-major transposes, GQA bookkeeping) happens here so the
-kernels see clean [B, H, S, D] blocks.  ``interpret`` defaults to True off-TPU
-so the same call sites run the kernel *body* on CPU for validation.
+kernels see clean [B, H, S, D] blocks.  ``interpret`` defaults to True on the
+CPU only, so the same call sites run the kernel *body* there for validation
+and compile it for the device everywhere else.
 """
 
 from __future__ import annotations
 
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -27,13 +28,13 @@ __all__ = [
     "rms_norm",
     "simplex_pivot",
     "asap_replay",
-    "scheduling_kernels_available",
+    "scheduling_kernels_error",
 ]
 
 
 def _interp(interpret):
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return jax.default_backend() == "cpu"
     return interpret
 
 
@@ -120,29 +121,29 @@ def asap_replay(w_cell, z, latency, tau, vcomm, vcomp, rel, valid, gamma,
     )
 
 
-_SCHED_KERNELS_OK: bool | None = None
+@cache
+def scheduling_kernels_error() -> str | None:
+    """Why the Pallas scheduling kernels cannot run on this backend, or None.
 
+    Probes once per process with a tiny pivot launch (interpret mode on the
+    CPU, compiled for the device everywhere else) and caches the answer.
+    Only a refusal by the Pallas lowering or the XLA compiler is caught; its
+    message is the returned reason, so a caller that needs the kernels can
+    fail with the compiler's own words.  Any other exception propagates.
+    """
+    from repro.jaxenv import x64
 
-def scheduling_kernels_available() -> bool:
-    """True when the Pallas scheduling kernels can actually run here.
-
-    Probes once with a tiny pivot call (interpret-gated like every other
-    call site) and caches the answer; the ``pallas`` solver backend uses
-    this to fall back to the plain batched engine instead of failing."""
-    global _SCHED_KERNELS_OK
-    if _SCHED_KERNELS_OK is None:
-        try:
-            from jax.experimental import enable_x64
-
-            with enable_x64():
-                T = jnp.zeros((1, 2, 3), jnp.float64).at[:, -1, 0].set(-1.0)
-                T = T.at[:, 0, 0].set(1.0).at[:, 0, -1].set(1.0)
-                out = simplex_pivot(
-                    T, jnp.ones((1, 1), jnp.int32), jnp.zeros(1, jnp.int32),
-                    jnp.full(1, -1, jnp.int32),
-                    ncols_price=2, bland_after=10, max_iter=10,
-                )
-                _SCHED_KERNELS_OK = int(out[3][0]) in (-1, 0, 2)
-        except Exception:  # pragma: no cover - platform-dependent
-            _SCHED_KERNELS_OK = False
-    return _SCHED_KERNELS_OK
+    try:
+        with x64():
+            T = jnp.zeros((1, 2, 3), jnp.float64).at[:, -1, 0].set(-1.0)
+            T = T.at[:, 0, 0].set(1.0).at[:, 0, -1].set(1.0)
+            out = simplex_pivot(
+                T, jnp.ones((1, 1), jnp.int32), jnp.zeros(1, jnp.int32),
+                jnp.full(1, -1, jnp.int32),
+                ncols_price=2, bland_after=10, max_iter=10,
+                interpret=_interp(None),
+            )
+            status = int(out[3][0])
+    except (NotImplementedError, ValueError, jax.errors.JaxRuntimeError) as e:
+        return f"{type(e).__name__}: {e}"
+    return None if status in (-1, 0, 2) else f"probe pivot returned status {status}"

@@ -95,12 +95,38 @@ def rms_norm_ref(x, w, eps=1e-5):
     return (xf * jax.lax.rsqrt(ms + eps) * w.astype(jnp.float32)).astype(x.dtype)
 
 
+def _harris_row_ref(colvals, rhs, basis, bland, eps):
+    """Harris two-pass ratio test, row by row in plain floats; the pivot
+    row, or None when the column is unbounded.
+
+    Candidates: entries above ``eps`` times the column's largest magnitude
+    (above ``eps`` alone when none is).  Pass one: the longest step that
+    keeps every basic variable above ``-eps``.  Pass two: among candidates
+    whose ratio fits that step, the first largest entry — or, under
+    ``bland``, the first smallest basis index.
+    """
+    col = [float(v) for v in colvals]
+    rhs = [float(v) for v in rhs]
+    big = max([abs(v) for v in col], default=0.0)
+    cands = [i for i, v in enumerate(col) if v > eps * max(1.0, big)]
+    if not cands:
+        cands = [i for i, v in enumerate(col) if v > eps]
+    if not cands:
+        return None
+    step = min((max(rhs[i], 0.0) + eps) / col[i] for i in cands)
+    fits = [i for i in cands if rhs[i] / col[i] <= step]
+    if bland:
+        return min(fits, key=lambda i: int(basis[i]))
+    return max(fits, key=lambda i: col[i])
+
+
 def simplex_pivot_ref(T, basis, it, status, *, ncols_price, bland_after, max_iter):
     """One masked simplex pivot per batch element, element-by-element.
 
     T [B,R,C], basis [B,R-1], it/status [B] -> the advanced stack.  Dantzig
-    pricing with a Bland fallback after ``bland_after``; ratio test tie-broken
-    on the smallest basis index; finished/exhausted elements pass through.
+    pricing with a Bland fallback after ``bland_after``; Harris's two-pass
+    ratio test (:func:`_harris_row_ref`); finished/exhausted elements pass
+    through.
     Statuses: -1 running, 0 optimal, 2 unbounded.
     """
     eps = 1e-9
@@ -122,16 +148,12 @@ def simplex_pivot_ref(T, basis, it, status, *, ncols_price, bland_after, max_ite
             col = int(jnp.argmin(obj))
         else:
             col = int(jnp.argmin(jnp.where(neg, jnp.arange(ncols_price), ncols_price)))
-        colvals = Tb[:m_rows, col]
-        pos = colvals > eps
-        ratios = jnp.where(pos, Tb[:m_rows, -1] / jnp.where(pos, colvals, 1.0), jnp.inf)
-        best = jnp.min(ratios)
-        if not bool(jnp.isfinite(best)):
+        row = _harris_row_ref(Tb[:m_rows, col], Tb[:m_rows, -1], bb,
+                              itb >= bland_after, eps)
+        if row is None:  # unbounded
             T_out.append(Tb), basis_out.append(bb)
             it_out.append(itb), status_out.append(jnp.int32(2))
             continue
-        ties = jnp.abs(ratios - best) <= 1e-12
-        row = int(jnp.argmin(jnp.where(ties, bb, jnp.iinfo(jnp.int32).max)))
         piv = Tb[row, col]
         Tb = Tb.at[row].divide(piv)
         colv = Tb[:, col].at[row].set(0.0)

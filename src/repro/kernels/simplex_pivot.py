@@ -7,11 +7,11 @@ fuses what the vmapped jnp path runs as separate HBM-roundtripping ops:
   1. *Dantzig pricing* over the objective row (with the Bland fallback after
      ``bland_after`` iterations — same anti-cycling rule as
      ``repro.engine.batched_simplex``);
-  2. the *ratio test* over the entering column, tie-broken on the smallest
-     basis index (the NumPy solver's rule);
-  3. the fused rank-1 update ``T -= outer(pcol', prow)`` where ``pcol'``
-     carries ``piv - 1`` at the pivot row, so eliminating the column and
-     rescaling the pivot row are one pass over the tableau.
+  2. the *ratio test* over the entering column — the Harris rule the
+     engine uses too, :func:`repro.pivoting.harris_row`;
+  3. the fused update: every other row subtracts ``pcol * prow`` and the
+     pivot row becomes ``prow = T[row] / piv``, in one pass over the
+     tableau (the engine's ``_fused_pivot``).
 
 ``k_pivots`` chains K of these pricing→ratio→update rounds per launch with
 the convergence check *in-kernel* (a ``fori_loop`` whose body re-evaluates
@@ -24,7 +24,7 @@ driver in ``repro.engine.batched_simplex`` picks it per tableau shape via
 the autotune sweep (``repro.engine.autotune``).
 
 Finished batch elements (status != running, or out of iteration budget) are
-masked *in-kernel*: their ``pcol'`` is zeroed wholesale, so the rank-1 update
+masked *in-kernel*: their ``pcol`` is zeroed wholesale, so the rank-1 update
 is the identity and their tableau/basis/counters pass through unchanged —
 which is also why K fused pivots are bit-identical to K single-pivot
 launches (parity-tested in tests/test_hotpath.py).
@@ -46,6 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.pivoting import harris_row
 
 __all__ = ["simplex_pivot_kernel", "simplex_pivot_call"]
 
@@ -76,27 +78,27 @@ def _one_pivot(T, basis, it, status, *, ncols_price: int, bland_after: int,
     pcol_full = T @ e_col.astype(T.dtype)  # [R]
     colvals = pcol_full[:m_rows]
 
-    # ---- ratio test, tie-break on smallest basis index ----
-    pos = colvals > _EPS
-    ratios = jnp.where(pos, T[:m_rows, -1] / jnp.where(pos, colvals, 1.0), jnp.inf)
-    best = jnp.min(ratios)
-    unbounded = ~jnp.isfinite(best)
-    ties = jnp.abs(ratios - best) <= 1e-12
-    row = jnp.argmin(
-        jnp.where(ties, basis, jnp.iinfo(jnp.int32).max)
-    ).astype(jnp.int32)
+    # ---- ratio test: the Harris rule ----
+    row, unbounded = harris_row(colvals, T[:m_rows, -1], basis,
+                                it >= bland_after)
+    row = row.astype(jnp.int32)
     ridx = jax.lax.broadcasted_iota(jnp.int32, (m_rows, 1), 0)[:, 0]
     e_row = (ridx == row).astype(T.dtype)
 
     do_pivot = active & any_neg & ~unbounded
 
-    # ---- fused masked rank-1 update ----
+    # ---- fused masked rank-1 update (the engine's _fused_pivot) ----
     piv = jnp.where(do_pivot, e_row @ colvals, 1.0)
-    prow = (e_row @ T[:m_rows]) / piv  # [C] — the pivot row, pre-scaled
+    prow = (e_row @ T[:m_rows]) / piv  # [C] — the new pivot row
     full_ridx = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)[:, 0]
-    pcol = jnp.where(full_ridx == row, piv - 1.0, pcol_full)
-    pcol = jnp.where(do_pivot, pcol, 0.0)  # mask finished elements wholesale
-    T = T - pcol[:, None] * prow[None, :]
+    is_row = full_ridx == row
+    # finished elements: pcol zeroed wholesale and the row left as it was
+    pcol = jnp.where(do_pivot & ~is_row, pcol_full, 0.0)
+    T = jnp.where((do_pivot & is_row)[:, None], prow[None, :],
+                  T - pcol[:, None] * prow[None, :])
+    is_col = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)[:, 0] == col
+    T = jnp.where(do_pivot & is_col[None, :],
+                  is_row[:, None].astype(T.dtype), T)
 
     basis = jnp.where(do_pivot & (ridx == row), col.astype(basis.dtype), basis)
     new_status = jnp.where(

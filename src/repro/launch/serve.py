@@ -31,6 +31,7 @@ import numpy as np
 from repro.config import ShardingPolicy, get_arch, smoke_variant
 from repro.core.planner import BatchSpec, LinkSpec, Planner, StageSpec
 from repro.data import make_batch
+from repro.jaxenv import use_compile_cache
 from repro.models import decode_flops_per_token, init_params, prefill
 from repro.runtime import make_serve_step
 from repro.launch.mesh import HW
@@ -55,9 +56,10 @@ def main(argv=None):
     ap.add_argument("--plan", type=int, default=0,
                     help="also DLT-plan N request batches over a 4-stage platform")
     ap.add_argument("--plan-backend", default="batched",
-                    help="solver-backend registry entry for --plan "
-                         "(see repro.core.available_backends()); 'pallas' "
-                         "runs the engine's solve/replay in fused kernels")
+                    help="solver-backend registry entry for --plan and for "
+                         "the --serve plan server's policy (see "
+                         "repro.core.available_backends()); 'pallas' runs "
+                         "the engine's solve/replay in fused kernels")
     ap.add_argument("--topology", default="chain", choices=("chain", "star"),
                     help="platform family for --plan: the paper's linear "
                          "chain, or a one-port master star (stage 0 holds "
@@ -105,6 +107,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if not args.serve and args.arch is None:
         ap.error("--arch is required (unless running --serve)")
+    use_compile_cache()
 
     # observability surfaces (repro.obs): both are no-cost when unset
     metrics_server = None
@@ -142,9 +145,11 @@ def _run_server(args):
     Admitted work always drains before exit (SIGINT and --serve-duration
     both go through ``PlanServer.close()``), so Ctrl-C never drops a plan.
     """
+    from repro.api import Policy
     from repro.serve import PlanServer
 
     server = PlanServer(
+        policy=Policy(backend=args.plan_backend),
         store=args.serve_store,
         workers=args.serve_workers,
         queue_limit=args.serve_queue_limit,
@@ -153,7 +158,8 @@ def _run_server(args):
         port=args.serve_port,
     )
     print(f"plan server: http://localhost:{server.port}/v1/plan "
-          f"({args.serve_workers} workers, queue {args.serve_queue_limit}, "
+          f"(backend {args.plan_backend}, {args.serve_workers} workers, "
+          f"queue {args.serve_queue_limit}, "
           f"store={args.serve_store or 'in-memory'})")
     print(f"  healthz: http://localhost:{server.port}/healthz   "
           f"metrics: http://localhost:{server.port}/metrics")

@@ -26,14 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6: top-level export, replication check renamed to check_vma
-    from jax import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_vma": False}
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ArchConfig, ShardingPolicy, TrainConfig
@@ -236,12 +229,12 @@ def make_dlt_train_step(
 
     param_spec = P()  # replicated across the stage axis (DP chain)
 
-    smapped = _shard_map(
+    smapped = shard_map(
         chain_loss,
         mesh=mesh,
         in_specs=(param_spec, P(), P(), P()),
         out_specs=P(),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
 
     def step(state, tokens, labels, counts):

@@ -87,7 +87,8 @@ class PlanServer:
     built cache) persists plans across processes; ``None`` serves from a
     process-local in-memory cache only.  ``devices``/``n_shards`` forward
     to the engine's sharded fan-out (:mod:`repro.serve.shard`) for every
-    worker solve.
+    worker solve; they raise ``ValueError`` unless ``policy`` names an
+    engine backend, which is the only kind that can shard.
     """
 
     def __init__(
@@ -106,7 +107,7 @@ class PlanServer:
             raise ValueError("workers must be >= 1")
         if queue_limit < 1:
             raise ValueError("queue_limit must be >= 1")
-        from repro.api import Policy
+        from repro.api import Policy, Session
 
         self.default_policy = policy if policy is not None else Policy()
         self.default_deadline_s = default_deadline_s
@@ -117,18 +118,23 @@ class PlanServer:
         self._drained = threading.Event()
         self.cache = self._build_cache(store)
         self.sessions = []
-        self._workers: list = []
-        for i in range(workers):
-            from repro.api import Session
-
+        for _ in range(workers):
             s = Session(policy=self.default_policy, cache=self.cache,
                         max_batch=None)
             if devices is not None or n_shards is not None:
                 # the worker's engine handle fans buckets out across devices
-                h = s.backend(self.default_policy.backend)
-                if hasattr(h, "devices"):
-                    h.devices, h.n_shards = devices, n_shards
+                pol = self.default_policy
+                h = s.backend(pol.backend, fallback=pol.fallback,
+                              quantum=pol.cache_quantum)
+                if not hasattr(h, "devices"):
+                    raise ValueError(
+                        f"backend {pol.backend!r} cannot shard; devices/"
+                        "n_shards need an engine backend ('batched' or "
+                        "'pallas') in the server policy")
+                h.devices, h.n_shards = devices, n_shards
             self.sessions.append(s)
+        self._workers: list = []
+        for i, s in enumerate(self.sessions):
             t = threading.Thread(target=self._worker_loop, args=(i, s),
                                  name=f"plan-worker-{i}", daemon=True)
             t.start()

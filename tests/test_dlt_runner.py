@@ -65,7 +65,7 @@ print("OK")
 
 @pytest.mark.slow
 def test_chain_loss_matches_single_device():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env, cwd=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), capture_output=True, text=True,

@@ -205,6 +205,24 @@ def test_solve_bulk_matches_serial_solve():
         assert got.makespan <= got.lp_makespan * (1 + 1e-6) + 1e-9
 
 
+@pytest.mark.parametrize("seed", [1, 2])
+def test_solve_bulk_serves_extreme_comm_ratio_stars(seed):
+    """Stars with result return at the Table-2 grid's comm-to-comp extremes
+    (m=10, 10 loads): the exact min-ratio rule cycled to the iteration cap
+    or lost feasibility here and every one went to the serial rescue; the
+    Harris ratio test keeps them on the engine, at HiGHS's optimum."""
+    insts = [
+        random_instance(np.random.default_rng(seed), m=10, n_loads=10,
+                        heterogeneous=True, with_latency=True,
+                        topology="star", return_ratio=0.5, comm_to_comp=ccr)
+        for ccr in (0.01, 100.0)
+    ]
+    for inst, got in zip(insts, solve_bulk(insts)):
+        assert got.backend == "batched" and got.ok
+        ref = solve(inst, backend="scipy")
+        assert got.makespan == pytest.approx(ref.makespan, rel=1e-6)
+
+
 def test_solve_batch_serial_backend_is_reference():
     rng = np.random.default_rng(6)
     insts = [random_instance(rng, m=3, n_loads=2, q=1) for _ in range(4)]

@@ -187,7 +187,7 @@ def _mixed_status_batch(rng, B=8, n=5, mu=3, me=1):
 
 
 @pytest.mark.skipif(
-    not pytest.importorskip("repro.kernels.ops").scheduling_kernels_available(),
+    pytest.importorskip("repro.kernels.ops").scheduling_kernels_error() is not None,
     reason="Pallas scheduling kernels unavailable",
 )
 class TestCompactionEpochSimplex:
@@ -222,12 +222,12 @@ class TestCompactionEpochSimplex:
 
     def test_k_fused_pivots_bit_identical_to_sequential(self):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
+        from repro.jaxenv import x64
         from repro.kernels.ops import simplex_pivot
 
         rng = np.random.default_rng(11)
-        with enable_x64():
+        with x64():
             B, R, C = 4, 5, 9
             T = jnp.asarray(rng.normal(size=(B, R, C)))
             basis = jnp.asarray(

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.jaxenv import x64
 from repro.kernels import ops, ref
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5), jnp.bfloat16: dict(rtol=3e-2, atol=3e-2)}
@@ -198,10 +199,8 @@ def _random_tableau_stack(rng, B, R, C):
 
 @pytest.mark.parametrize("B,R,C", [(1, 2, 4), (4, 5, 8), (3, 7, 12)])
 def test_simplex_pivot_kernel_matches_ref(B, R, C):
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(0)
-    with enable_x64():
+    with x64():
         T, basis = _random_tableau_stack(rng, B, R, C)
         it = jnp.zeros(B, jnp.int32)
         status = jnp.full(B, -1, jnp.int32)
@@ -217,10 +216,8 @@ def test_simplex_pivot_kernel_matches_ref(B, R, C):
 
 
 def test_simplex_pivot_kernel_masks_finished_elements():
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(1)
-    with enable_x64():
+    with x64():
         T, basis = _random_tableau_stack(rng, 3, 4, 7)
         it = jnp.asarray([0, 0, 99], jnp.int32)
         status = jnp.asarray([-1, 0, -1], jnp.int32)  # b=1 done, b=2 exhausted
@@ -234,6 +231,32 @@ def test_simplex_pivot_kernel_masks_finished_elements():
         assert int(out[3][1]) == 0  # optimal stays optimal
 
 
+@pytest.mark.parametrize("bland_after,want_row", [(100, 2), (0, 1)])
+def test_simplex_pivot_harris_ratio_test(bland_after, want_row):
+    """Column 0 enters.  Row 0's entry is above the absolute pivot threshold
+    but below the relative one (an exact min-ratio rule would take it at
+    ratio 0); rows 1 and 2 tie
+    within Harris's step, where Dantzig takes the larger pivot (row 2) and
+    Bland the smaller basis index (row 1)."""
+    with x64():
+        T = jnp.asarray([[[2e-9, 1.0, 0.0, 0.0],
+                          [2.0, 0.0, 0.0, 1.0],
+                          [4.0, 1.0, 0.0, 2.0 + 1e-10],
+                          [-1.0, 0.0, 0.0, 0.0]]])
+        basis = jnp.asarray([[5, 6, 7]], jnp.int32)
+        it = jnp.zeros(1, jnp.int32)
+        status = jnp.full(1, -1, jnp.int32)
+        kw = dict(ncols_price=2, bland_after=bland_after, max_iter=50)
+        out = ops.simplex_pivot(T, basis, it, status, interpret=True, **kw)
+        want = ref.simplex_pivot_ref(T, basis, it, status, **kw)
+        for got, exp in zip(out, want):
+            np.testing.assert_allclose(np.asarray(got, np.float64),
+                                       np.asarray(exp, np.float64),
+                                       rtol=0, atol=1e-12)
+        assert np.asarray(out[1])[0].tolist() == [
+            0 if r == want_row else b for r, b in enumerate([5, 6, 7])]
+
+
 def _random_replay_batch(rng, B, m, T):
     mk = lambda *s: jnp.abs(jnp.asarray(rng.normal(size=s)))
     return (mk(B, m, T) + 0.1, mk(B, m - 1) + 0.1, mk(B, m - 1) * 0.01,
@@ -243,10 +266,8 @@ def _random_replay_batch(rng, B, m, T):
 
 @pytest.mark.parametrize("B,m,T", [(1, 2, 1), (3, 4, 5), (2, 6, 8)])
 def test_asap_replay_kernel_matches_ref(B, m, T):
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(2)
-    with enable_x64():
+    with x64():
         args = _random_replay_batch(rng, B, m, T)
         out = ops.asap_replay(*args, interpret=True)
         want = ref.asap_replay_ref(*args)
@@ -257,10 +278,8 @@ def test_asap_replay_kernel_matches_ref(B, m, T):
 
 
 def test_asap_replay_kernel_masks_padded_cells():
-    from jax.experimental import enable_x64
-
     rng = np.random.default_rng(3)
-    with enable_x64():
+    with x64():
         args = list(_random_replay_batch(rng, 2, 3, 6))
         valid = jnp.asarray([1.0, 1.0, 1.0, 1.0, 0.0, 0.0])
         # padded trailing cells: zero volumes/releases, latency masked by valid
@@ -274,4 +293,23 @@ def test_asap_replay_kernel_masks_padded_cells():
 
 
 def test_scheduling_kernels_available_probe():
-    assert ops.scheduling_kernels_available() is True  # interpret mode runs anywhere
+    assert ops.scheduling_kernels_error() is None  # interpret mode on the CPU
+
+
+def test_pallas_selection_raises_where_kernels_cannot_compile(monkeypatch):
+    """Selecting 'pallas' where its kernels do not compile raises with the
+    compiler's own reason instead of quietly serving 'batched'.  Steered
+    here by turning interpret mode off, which the CPU backend refuses —
+    the same path a device that rejects the kernels takes."""
+    from repro.api import Policy, Problem, Session
+
+    monkeypatch.setattr(
+        ops, "_interp", lambda interpret: False if interpret is None else interpret)
+    ops.scheduling_kernels_error.cache_clear()
+    try:
+        assert "interpret mode" in ops.scheduling_kernels_error()
+        problem = Problem(w=[1.0, 2.0], z=[0.5], v_comm=[1.0], v_comp=[1.0])
+        with pytest.raises(RuntimeError, match="pallas backend cannot run on cpu"):
+            Session(policy=Policy(backend="pallas")).solve(problem)
+    finally:
+        ops.scheduling_kernels_error.cache_clear()  # re-probe unpatched next time

@@ -6,7 +6,7 @@
   Prometheus text exposition, the HTTP exposition server, NullRegistry;
 * wiring: engine telemetry on reports/artifacts (bit-stable v2 round-trip,
   v1 documents still bit-stable), structured provenance events on the
-  serial-rescue / pallas-degrade / error paths, unified stats shims.
+  serial-rescue / error paths, unified stats shims.
 """
 
 from __future__ import annotations
@@ -350,21 +350,19 @@ def test_serial_rescue_structured_event(registry, monkeypatch):
 
 
 def test_pallas_degrade_structured_event(registry, monkeypatch):
-    """With the fused kernels unavailable, 'pallas' serves via the plain
-    batched path: a degrade event on the artifact + the degrade counter."""
+    """Where the fused kernels cannot run, 'pallas' no longer degrades to
+    the plain batched path: selecting it raises with the probe's reason,
+    and nothing is served or recorded as a degrade."""
     import repro.kernels.ops as kops
     from repro.api import Policy, Session
 
-    monkeypatch.setattr(kops, "scheduling_kernels_available", lambda: False)
+    monkeypatch.setattr(kops, "scheduling_kernels_error",
+                        lambda: "NotImplementedError: refused by the lowering")
     s = Session(policy=Policy(backend="pallas", installments=2))
-    art = s.solve(_chain_problem())
-    assert art.ok and art.backend == "batched"
-    (ev,) = art.events
-    assert ev == {"kind": "degrade", "backend": "batched", "reason": ""}
-    assert art.fallback_events == ("served_by:batched",)
-    assert registry.value("repro_engine_pallas_degrade_total",
-                          reason="kernels_unavailable") == 1.0
-    assert registry.value("repro_session_events_total", kind="degrade") == 1.0
+    with pytest.raises(RuntimeError, match="refused by the lowering"):
+        s.solve(_chain_problem())
+    assert not any("degrade" in k for k in registry.snapshot())
+    assert registry.value("repro_session_events_total", kind="degrade") == 0.0
 
 
 def test_error_artifact_preserves_class_and_truncates_at_word(registry):

@@ -103,6 +103,46 @@ def test_store_backed_server_restart_serves_hits(tmp_path):
         assert second.cache.store_hits == 1
 
 
+def test_worker_threads_keep_float64_device_outputs(monkeypatch):
+    """JAX's x64 scope is thread-local: the engine enters it itself, so the
+    solve and replay programs a worker thread runs return float64 — a
+    silent float32 downcast would change the numerics."""
+    import repro.engine.batched_sim as bsim
+    import repro.engine.batched_simplex as bsx
+
+    seen = []
+
+    def spy(real, name):
+        def wrapped(*args):
+            out = real(*args)
+            seen.append((name, threading.current_thread().name,
+                         {str(o.dtype) for o in out if o.dtype.kind == "f"}))
+            return out
+        return wrapped
+
+    monkeypatch.setattr(bsx, "_solve_batch", spy(bsx._solve_batch, "solve"))
+    monkeypatch.setattr(bsim, "_sim_batch", spy(bsim._sim_batch, "replay"))
+    with PlanServer(workers=1, policy=_POLICY) as server:
+        assert server.plan(_problem(1.7)).ok
+    assert {name for name, _, _ in seen} == {"solve", "replay"}
+    for name, thread, dtypes in seen:
+        assert thread.startswith("plan-worker"), (name, thread)
+        assert dtypes == {"float64"}, (name, dtypes)
+
+
+def test_sharding_needs_an_engine_backend():
+    """devices/n_shards reach the worker's engine handle, and a backend
+    that cannot shard refuses them instead of dropping them."""
+    with pytest.raises(ValueError, match="cannot shard"):
+        PlanServer(workers=1, policy=Policy(installments=2), n_shards=2)
+    strict = Policy(installments=2, backend="batched", fallback=False)
+    with PlanServer(workers=2, policy=strict, n_shards=2) as server:
+        for s in server.sessions:
+            h = s.backend("batched", fallback=False)
+            assert h.n_shards == 2 and h.devices is None
+        assert server.plan(_problem(1.1)).ok
+
+
 # ---------------- admission: backpressure + deadlines ----------------
 
 

@@ -187,9 +187,9 @@ print("parity", diff)
 def test_sharded_parity_two_real_devices():
     # smoke tests elsewhere must keep seeing 1 device, so the forced-host
     # multi-device run happens in a subprocess (the dlt_runner idiom)
-    env = dict(os.environ, PYTHONPATH=os.path.join(
+    # forced host devices exist only on the CPU backend
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr

@@ -149,7 +149,7 @@ def test_store_two_process_hammer(tmp_path):
         "st.close()\n"
         "print('done')\n"
     )
-    env = dict(os.environ, PYTHONPATH=os.path.join(
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
     proc = subprocess.Popen([sys.executable, "-c", script, str(path)],
                             env=env, stdout=subprocess.PIPE,
