@@ -461,7 +461,8 @@ class Session:
                     for i, p in enumerate(problems)
                 ]
                 self._solve_pending(work)
-                return [w.ticket._materialize() for w in work]
+                with obs_trace.span("session.materialize", n=len(work)):
+                    return [w.ticket._materialize() for w in work]
 
     def evaluate_gammas(self, instances, gammas, use_batched: bool = True) -> np.ndarray:
         """Achieved makespans of explicit fraction assignments (bulk replay).

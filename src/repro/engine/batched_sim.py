@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 from repro.core.schedule import Schedule
 from repro.jaxenv import x64
+from repro.obs.trace import span
 
 from .arena import InstanceArena, PackedBucket
 
@@ -259,14 +260,17 @@ def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray,
         retr = bucket.ret_cell
         valid = np.asarray(bucket.cell_valid, dtype=np.float64)
         g = np.asarray(gamma, dtype=np.float64)
-        if use_pallas and bucket.m >= 2:
-            from repro.kernels.ops import asap_replay  # deferred kernel import
+        # host spans, as in the simplex: the call, then the fetch that waits
+        with span("replay.dispatch", B=g.shape[0]):
+            if use_pallas and bucket.m >= 2:
+                from repro.kernels.ops import asap_replay  # deferred kernel import
 
-            out = asap_replay(*args, valid, g, retr if with_ret else None,
-                              topology=bucket.topology)
-        else:
-            out = _sim_batch(*args, retr, valid, g, bucket.topology, with_ret)
-        out = tuple(np.asarray(o) for o in out)
+                out = asap_replay(*args, valid, g, retr if with_ret else None,
+                                  topology=bucket.topology)
+            else:
+                out = _sim_batch(*args, retr, valid, g, bucket.topology, with_ret)
+        with span("replay.fetch", B=g.shape[0]):
+            out = tuple(np.asarray(o) for o in out)
         if not with_ret:  # normalize the 5-slot kernel output to 7 slots
             out = out[:4] + (None, None) + out[4:]
         return out

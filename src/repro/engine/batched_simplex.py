@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.jaxenv import x64
+from repro.obs.trace import span
 from repro.pivoting import harris_row
 
 __all__ = ["BatchedSimplexResult", "solve_simplex_batched", "STATUS"]
@@ -702,29 +703,35 @@ def solve_simplex_batched(
             ci, Aui, bui = (c[cold_idx], A_ub[cold_idx], b_ub[cold_idx]) if sub \
                 else (c, A_ub, b_ub)
             Aei, bei = (A_eq[cold_idx], b_eq[cold_idx]) if sub else (A_eq, b_eq)
-            if use_pallas and m_rows > 0:
-                from repro.kernels.ops import _interp  # the kernels' TPU gate
+            # host spans: the call (argument transfer and dispatch; the
+            # device runs on asynchronously), the fetch of its outputs
+            # (which waits for the device), and the host's feasibility pass
+            with span("simplex.dispatch", B=len(cold_idx)):
+                if use_pallas and m_rows > 0:
+                    from repro.kernels.ops import _interp  # the kernels' TPU gate
 
-                cc = compact
-                if cc is None:
-                    cc = len(cold_idx) >= 2  # epochs need lanes to retire
-                driver = (_solve_batch_pallas_compact if cc
-                          else _solve_batch_pallas)
-                out = driver(ci, Aui, bui, Aei, bei, int(max_iter),
-                             _interp(interpret))
-            else:
-                out = _solve_batch(ci, Aui, bui, Aei, bei, int(max_iter))
-            cx, cobj, cst, cit, cit1, cit2, cbasis = out
-            x[cold_idx] = np.asarray(cx)
-            obj[cold_idx] = np.asarray(cobj)
-            status[cold_idx] = np.asarray(cst)
-            iters[cold_idx] = np.asarray(cit)
-            it1[cold_idx] = np.asarray(cit1)
-            it2[cold_idx] = np.asarray(cit2)
-            if basis_out is not None:
-                basis_out[cold_idx] = np.asarray(cbasis)
+                    cc = compact
+                    if cc is None:
+                        cc = len(cold_idx) >= 2  # epochs need lanes to retire
+                    driver = (_solve_batch_pallas_compact if cc
+                              else _solve_batch_pallas)
+                    out = driver(ci, Aui, bui, Aei, bei, int(max_iter),
+                                 _interp(interpret))
+                else:
+                    out = _solve_batch(ci, Aui, bui, Aei, bei, int(max_iter))
+            with span("simplex.fetch", B=len(cold_idx)):
+                cx, cobj, cst, cit, cit1, cit2, cbasis = out
+                x[cold_idx] = np.asarray(cx)
+                obj[cold_idx] = np.asarray(cobj)
+                status[cold_idx] = np.asarray(cst)
+                iters[cold_idx] = np.asarray(cit)
+                it1[cold_idx] = np.asarray(cit1)
+                it2[cold_idx] = np.asarray(cit2)
+                if basis_out is not None:
+                    basis_out[cold_idx] = np.asarray(cbasis)
 
-        status = _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq)
+        with span("simplex.demote", B=B):
+            status = _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq)
         return BatchedSimplexResult(
             x=x,
             objective=obj,

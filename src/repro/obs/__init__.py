@@ -18,7 +18,9 @@ pipeline:
 
 Nothing in here imports JAX, numpy, or anything outside the stdlib — the
 flight recorder must be importable (and near-free) everywhere, including
-the serial-only paths.
+the serial-only paths.  The one exception is asked for: a
+``Tracer(annotate=True)`` imports ``jax.profiler`` to mirror its spans into
+the profiler's trace.
 """
 
 from .metrics import (MetricsRegistry, NullRegistry, get_registry,

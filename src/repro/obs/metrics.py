@@ -19,8 +19,9 @@ low-cardinality dimensions (backend, topology, status, phase, stage).
   :class:`NullRegistry`).
 
 Everything is stdlib-only and lock-protected; a counter bump is two dict
-lookups and a float add, so always-on metrics cost <=5% of even the
-smallest bucket solve (measured by scripts/traced_smoke.py).
+lookups and a float add.  What the always-on metrics and the tracer cost
+on the chip is read from the benchmark's traced run against its untraced
+runs (``bench/run.py --trace 1`` and ``--trace 0``, PERF.md).
 """
 
 from __future__ import annotations
@@ -194,7 +195,7 @@ class MetricsRegistry:
 
 class NullRegistry(MetricsRegistry):
     """A registry that drops everything — the disabled-metrics baseline for
-    overhead measurements (scripts/traced_smoke.py)."""
+    overhead measurements."""
 
     def inc(self, name, value=1.0, **labels):  # noqa: D102
         pass

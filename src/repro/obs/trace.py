@@ -15,10 +15,18 @@ Design constraints (DESIGN.md §8):
 * **exportable** — ``to_chrome_trace()`` emits the Trace Event Format
   (``ph: "X"`` complete events, microsecond timestamps) that
   ``chrome://tracing`` and Perfetto load directly; ``save(path)`` writes
-  it as JSON.
+  it as JSON;
+* **on the profiler's clock when asked** — ``Tracer(annotate=True)``
+  mirrors every span into ``jax.profiler.TraceAnnotation``, so a profile
+  taken with a host tracer level of 1 or more holds the same spans on its
+  own clock, beside the device's events.  JAX is imported then and only
+  then: without it this module stays stdlib-only.
 
 Nesting needs no explicit bookkeeping: complete events nest by timestamp
 containment per thread, which the context-manager discipline guarantees.
+An interval that starts on one thread and ends on another (a request's
+wait in a queue) is recorded whole with :func:`record`, from its two
+``perf_counter_ns`` readings, on the thread it is given.
 """
 
 from __future__ import annotations
@@ -27,7 +35,7 @@ import json
 import threading
 import time
 
-__all__ = ["Tracer", "span", "activate", "get_tracer", "NOOP_SPAN"]
+__all__ = ["Tracer", "span", "record", "activate", "get_tracer", "NOOP_SPAN"]
 
 
 class _NoopSpan:
@@ -52,23 +60,31 @@ class _Span:
     """One live span; records itself on ``__exit__`` (always, even when an
     exception is propagating — the event is tagged with the class name)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0
+        self._mirror = None
 
     def __enter__(self):
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            self._mirror = annotation(self.name)
+            self._mirror.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter_ns() - self._t0
+        t1 = time.perf_counter_ns()
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        self._tracer._record(self.name, self._t0, dur, self.args)
+        self._tracer._record(self.name, self._t0, t1 - self._t0,
+                             threading.get_ident(), self.args)
         return False
 
     def set(self, **attrs):
@@ -78,21 +94,38 @@ class _Span:
 
 
 class Tracer:
-    """Collects spans; export with :meth:`to_chrome_trace` / :meth:`save`."""
+    """Collects spans; export with :meth:`to_chrome_trace` / :meth:`save`.
 
-    def __init__(self, process_name: str = "repro"):
+    ``annotate=True`` also opens a ``jax.profiler.TraceAnnotation`` of the
+    same name around each span (not around :meth:`record`'s intervals,
+    which no one thread holds)."""
+
+    def __init__(self, process_name: str = "repro", annotate: bool = False):
         self.process_name = process_name
         self._lock = threading.Lock()
         self._events: list = []  # (name, t0_ns, dur_ns, tid, args)
         self._epoch_ns = time.perf_counter_ns()
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
 
     # ---------------- recording ----------------
 
     def span(self, name: str, **args) -> _Span:
         return _Span(self, name, args)
 
-    def _record(self, name: str, t0_ns: int, dur_ns: int, args: dict) -> None:
-        tid = threading.get_ident()
+    def record(self, name: str, t0_ns: int, t1_ns: int, tid: int | None = None,
+               **args) -> None:
+        """Record a complete event from ``t0_ns`` to ``t1_ns``
+        (``time.perf_counter_ns`` readings) on thread ``tid`` (the calling
+        thread's ``threading.get_ident()`` when None)."""
+        self._record(name, t0_ns, t1_ns - t0_ns,
+                     threading.get_ident() if tid is None else tid, args)
+
+    def _record(self, name: str, t0_ns: int, dur_ns: int, tid: int,
+                args: dict) -> None:
         with self._lock:
             self._events.append((name, t0_ns, dur_ns, tid, args))
 
@@ -192,3 +225,11 @@ def span(name: str, **args):
     if tr is None:
         return NOOP_SPAN
     return tr.span(name, **args)
+
+
+def record(name: str, t0_ns: int, t1_ns: int, tid: int | None = None,
+           **args) -> None:
+    """:meth:`Tracer.record` on the active tracer; nothing when tracing is off."""
+    tr = _ACTIVE
+    if tr is not None:
+        tr.record(name, t0_ns, t1_ns, tid, **args)

@@ -19,8 +19,15 @@
 * **observability** — ``/healthz`` reports queue depth/worker/drain state
   as JSON; ``/metrics`` serves the process :mod:`repro.obs.metrics`
   registry in the Prometheus text format; every request lands in
-  ``repro_serve_requests_total{status=...}`` and the
-  ``repro_serve_request_seconds`` histogram.
+  ``repro_serve_requests_total{status=...}``, the
+  ``repro_serve_request_seconds`` histogram (admission to answer) and the
+  ``repro_serve_queue_wait_seconds`` histogram (admission to a worker's
+  dequeue).  Under an active tracer each request has an id and each
+  coalesced batch one too: ``serve.http_accept`` (from the connection's
+  accept to the handler's start), ``serve.http_decode`` and
+  ``serve.http_encode`` carry the request's, ``serve.request_batch`` the
+  batch's, and ``serve.queue_wait`` (recorded on the admitting thread)
+  both.
 * **graceful drain** — ``close()`` stops admission, lets every already-
   admitted job solve, joins the workers, then stops the HTTP listener.
   Nothing admitted is ever lost; nothing new is accepted while draining.
@@ -45,13 +52,14 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import itertools
 import json
 import queue
 import threading
 import time
 
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import span
+from repro.obs.trace import record, span
 
 __all__ = ["PlanServer", "ServerBusy", "DeadlineExceeded", "ServerClosed"]
 
@@ -74,7 +82,11 @@ class _Job:
     policy: object
     deadline: float | None  # absolute time.monotonic()
     future: concurrent.futures.Future
-    admitted: float  # time.perf_counter() at admission (queue-wait metric)
+    request_id: int
+    # time.perf_counter_ns() at admission, on thread ``admitted_tid``: the
+    # start of repro_serve_queue_wait_seconds and repro_serve_request_seconds
+    admitted_ns: int
+    admitted_tid: int
 
 
 _SENTINEL = object()
@@ -113,6 +125,8 @@ class PlanServer:
         self.default_deadline_s = default_deadline_s
         self.max_batch = max(1, int(max_batch))
         self._met = obs_metrics.get_registry()
+        self._request_ids = itertools.count()
+        self._batch_ids = itertools.count()
         self._queue: queue.Queue = queue.Queue(maxsize=queue_limit)
         self._closed = threading.Event()
         self._drained = threading.Event()
@@ -168,6 +182,12 @@ class PlanServer:
         when the bounded queue is full — the caller (or the HTTP layer)
         owns the retry policy; the server never buffers beyond its bound.
         """
+        return self._admit(problem, policy, deadline_s, next(self._request_ids))
+
+    def _admit(self, problem, policy, deadline_s, request_id: int
+               ) -> concurrent.futures.Future:
+        """:meth:`submit` under a given request id (the HTTP layer draws it
+        when the request arrives, so its decode span carries it too)."""
         if self._closed.is_set():
             self._met.inc("repro_serve_rejects_total", reason="closed")
             raise ServerClosed("server is draining; not accepting work")
@@ -178,7 +198,9 @@ class PlanServer:
                    policy=policy if policy is not None else self.default_policy,
                    deadline=deadline,
                    future=concurrent.futures.Future(),
-                   admitted=time.perf_counter())
+                   request_id=request_id,
+                   admitted_ns=time.perf_counter_ns(),
+                   admitted_tid=threading.get_ident())
         try:
             self._queue.put_nowait(job)
         except queue.Full:
@@ -212,6 +234,13 @@ class PlanServer:
                     self._queue.put(_SENTINEL)  # keep the pool's shutdown count
                     break
                 batch.append(nxt)
+            taken_ns = time.perf_counter_ns()
+            batch_id = next(self._batch_ids)
+            for j in batch:
+                self._met.observe("repro_serve_queue_wait_seconds",
+                                  (taken_ns - j.admitted_ns) / 1e9)
+                record("serve.queue_wait", j.admitted_ns, taken_ns,
+                       j.admitted_tid, request=j.request_id, batch=batch_id)
             now = time.monotonic()
             live: list = []
             for j in batch:
@@ -227,7 +256,8 @@ class PlanServer:
                 continue
             t0 = time.perf_counter()
             try:
-                with span("serve.request_batch", worker=idx, n=len(live)):
+                with span("serve.request_batch", worker=idx, n=len(live),
+                          batch=batch_id):
                     # per-job policies: group identical ones into one call
                     arts = self._solve_batch(session, live)
             except Exception as e:
@@ -239,7 +269,7 @@ class PlanServer:
             dt = time.perf_counter() - t0
             for j, art in zip(live, arts):
                 self._met.observe("repro_serve_request_seconds",
-                                  (time.perf_counter() - j.admitted))
+                                  (time.perf_counter_ns() - j.admitted_ns) / 1e9)
                 self._met.inc("repro_serve_requests_total",
                               status=art.status if art is not None else "error")
                 j.future.set_result(art)
@@ -321,6 +351,17 @@ class PlanServer:
         import http.server
 
         server = self
+        accepted: dict = {}  # connection fd -> time.perf_counter_ns() at accept
+
+        class HTTPServer(http.server.ThreadingHTTPServer):
+            def get_request(self):
+                conn, addr = super().get_request()
+                accepted[conn.fileno()] = time.perf_counter_ns()
+                return conn, addr
+
+            def shutdown_request(self, request):
+                accepted.pop(request.fileno(), None)
+                super().shutdown_request(request)
 
         class Handler(http.server.BaseHTTPRequestHandler):
             def _send(self, code: int, body: bytes,
@@ -350,24 +391,32 @@ class PlanServer:
                 if self.path != "/v1/plan":
                     self._send_json(404, {"error": "not found", "kind": "http"})
                     return
+                rid = next(server._request_ids)
+                # accept to here: the handler thread's start, the request
+                # line and the headers
+                record("serve.http_accept",
+                       accepted[self.connection.fileno()],
+                       time.perf_counter_ns(), request=rid)
                 try:
-                    length = int(self.headers.get("Content-Length", 0))
-                    req = json.loads(self.rfile.read(length))
-                    from repro.api.artifact import (
-                        policy_from_dict,
-                        problem_from_dict,
-                    )
+                    with span("serve.http_decode", request=rid):
+                        length = int(self.headers.get("Content-Length", 0))
+                        req = json.loads(self.rfile.read(length))
+                        from repro.api.artifact import (
+                            policy_from_dict,
+                            problem_from_dict,
+                        )
 
-                    problem = problem_from_dict(req["problem"])
-                    policy = (policy_from_dict(req["policy"])
-                              if req.get("policy") is not None else None)
-                    deadline_s = req.get("deadline_s")
+                        problem = problem_from_dict(req["problem"])
+                        policy = (policy_from_dict(req["policy"])
+                                  if req.get("policy") is not None else None)
+                        deadline_s = req.get("deadline_s")
                 except Exception as e:
                     self._send_json(
                         400, {"error": str(e), "kind": "bad_request"})
                     return
                 try:
-                    art = server.plan(problem, policy, deadline_s)
+                    art = server._admit(problem, policy, deadline_s,
+                                        rid).result(timeout=deadline_s)
                 except ServerBusy as e:
                     self._send_json(429, {"error": str(e), "kind": "busy"})
                 except ServerClosed as e:
@@ -379,13 +428,14 @@ class PlanServer:
                     self._send_json(500, {"error": str(e), "kind": "error"})
                 else:
                     # the artifact's own canonical encoding IS the wire body
-                    self._send(200, ("{\"artifact\":" + art.to_json() + "}")
-                               .encode())
+                    with span("serve.http_encode", request=rid):
+                        self._send(200, ("{\"artifact\":" + art.to_json()
+                                         + "}").encode())
 
             def log_message(self, *args):  # keep request noise off stderr
                 pass
 
-        http_server = http.server.ThreadingHTTPServer(("", port), Handler)
+        http_server = HTTPServer(("", port), Handler)
         t = threading.Thread(target=http_server.serve_forever, daemon=True,
                              name=f"plan-server:{http_server.server_address[1]}")
         t.start()

@@ -128,6 +128,75 @@ def test_activate_restores_previous():
         ot.activate(None)
 
 
+def test_record_is_a_complete_event_on_the_given_thread(tracer):
+    import threading
+    import time
+
+    with ot.span("outer"):
+        t0 = time.perf_counter_ns()
+        t1 = t0 + 2_000_000
+        ot.record("wait", t0, t1, request=3)
+        ot.record("elsewhere", t0, t1, tid=12345)
+    outer, wait, other = (next(e for e in tracer.events() if e["name"] == n)
+                          for n in ("outer", "wait", "elsewhere"))
+    assert wait["dur_us"] == pytest.approx(2000.0)
+    assert wait["args"] == {"request": 3}
+    assert wait["tid"] == outer["tid"] == threading.get_ident()
+    assert other["tid"] == 12345
+    # starts inside its parent; events() lists the parent first
+    names = [e["name"] for e in tracer.events()]
+    assert names.index("outer") < names.index("wait")
+    assert outer["ts_us"] <= wait["ts_us"] <= outer["ts_us"] + outer["dur_us"]
+
+
+def test_record_without_a_tracer_does_nothing():
+    assert ot.get_tracer() is None
+    ot.record("ignored", 0, 10, request=1)  # no tracer, no error
+
+
+def test_annotate_mirrors_each_span_into_the_profiler(monkeypatch):
+    import jax.profiler
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = ot.Tracer(annotate=True)
+    prev = ot.activate(tr)
+    try:
+        with ot.span("a"):
+            with ot.span("b"):
+                pass
+        ot.record("c", 0, 1)  # an interval no thread holds: not mirrored
+    finally:
+        ot.activate(prev)
+    assert seen == [("enter", "a"), ("enter", "b"), ("exit", "b"), ("exit", "a")]
+    assert len(tr) == 3
+
+
+def test_tracer_without_annotations_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys; from repro.obs import trace as ot; "
+            "tr = ot.Tracer(); ot.activate(tr)\n"
+            "with ot.span('x'): pass\n"
+            "assert len(tr) == 1 and 'jax' not in sys.modules")
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60,
+                   env=dict(os.environ, PYTHONPATH=src))
+
+
 # --------------------------------------------------------------------------
 # metrics
 # --------------------------------------------------------------------------
@@ -421,8 +490,9 @@ def test_padding_waste_gauge(registry):
 
 def test_traced_session_run_covers_engine(registry):
     """A traced Session chain run emits the engine-stage spans the flight
-    recorder promises (the full >=90% coverage gate runs in
-    scripts/traced_smoke.py; this is the structural contract)."""
+    recorder promises (their times on the chip are read by the traced
+    benchmark run, ``bench/run.py --trace 1``; this is the structural
+    contract)."""
     from repro.api import Policy, Session
 
     s = Session(policy=Policy(backend="batched", installments=2))
@@ -430,6 +500,76 @@ def test_traced_session_run_covers_engine(registry):
         s.solve_bulk([_chain_problem(i) for i in range(3)])
     names = {e["name"] for e in tr.events()}
     assert {"session.trace", "session.solve_bulk", "session.dispatch",
-            "engine.solve_bulk", "engine.pack", "engine.lp_build",
-            "engine.simplex", "engine.replay"} <= names
+            "session.materialize", "engine.solve_bulk", "engine.pack",
+            "engine.lp_build", "engine.simplex", "simplex.dispatch",
+            "simplex.fetch", "simplex.demote", "engine.replay",
+            "replay.dispatch", "replay.fetch"} <= names
     assert ot.get_tracer() is None  # trace() restored the previous tracer
+
+
+def test_simplex_host_spans_nest_inside_the_simplex_span(registry):
+    from repro.api import Policy, Session
+
+    s = Session(policy=Policy(backend="batched"))
+    with s.trace() as tr:
+        s.solve_bulk([_chain_problem(i) for i in range(2)])
+    evs = tr.events()
+    (simplex,) = [e for e in evs if e["name"] == "engine.simplex"]
+    end = simplex["ts_us"] + simplex["dur_us"]
+    parts = [e for e in evs if e["name"].startswith("simplex.")]
+    assert [e["name"] for e in parts] == ["simplex.dispatch", "simplex.fetch",
+                                          "simplex.demote"]
+    for e in parts:
+        assert simplex["ts_us"] <= e["ts_us"] and e["ts_us"] + e["dur_us"] <= end
+        assert e["tid"] == simplex["tid"] and e["args"]["B"] == 2
+
+
+def test_served_requests_share_ids_across_spans_and_wait_in_a_histogram(registry):
+    from repro.api import Policy
+    from repro.serve import PlanClient, PlanServer
+
+    tr = ot.Tracer()
+    prev = ot.activate(tr)
+    try:
+        with PlanServer(workers=1, policy=Policy(backend="batched"),
+                        port=0) as server:
+            client = PlanClient(f"http://localhost:{server.port}")
+            for i in range(3):
+                assert client.plan(_chain_problem(i)).ok
+            server.plan(_chain_problem(9))  # in-process: no HTTP spans
+    finally:
+        ot.activate(prev)
+    evs = tr.events()
+    by = {n: [e for e in evs if e["name"] == n]
+          for n in ("serve.http_accept", "serve.http_decode",
+                    "serve.http_encode", "serve.queue_wait",
+                    "serve.request_batch")}
+    assert len(by["serve.http_decode"]) == len(by["serve.http_encode"]) == 3
+    # the accept span ends where the handler starts the decode, same thread
+    for acc, dec in zip(by["serve.http_accept"], by["serve.http_decode"]):
+        assert acc["args"] == dec["args"] and acc["tid"] == dec["tid"]
+        assert acc["ts_us"] + acc["dur_us"] <= dec["ts_us"]
+    assert len(by["serve.queue_wait"]) == 4
+    http_ids = [e["args"]["request"] for e in by["serve.http_decode"]]
+    assert sorted(http_ids) == sorted(
+        e["args"]["request"] for e in by["serve.http_encode"])
+    waits = {e["args"]["request"]: e for e in by["serve.queue_wait"]}
+    assert len(waits) == 4 and set(http_ids) < set(waits)
+    batches = {e["args"]["batch"]: e for e in by["serve.request_batch"]}
+    for rid in http_ids:
+        decode = next(e for e in by["serve.http_decode"]
+                      if e["args"]["request"] == rid)
+        wait = waits[rid]
+        # the wait starts on the handler thread that decoded the request,
+        # after the decode, and ends before the batch that took it
+        assert wait["tid"] == decode["tid"]
+        assert decode["ts_us"] + decode["dur_us"] <= wait["ts_us"]
+        batch = batches[wait["args"]["batch"]]
+        assert wait["ts_us"] + wait["dur_us"] <= batch["ts_us"]
+        assert batch["tid"] != wait["tid"]
+    snap = registry.snapshot()
+    assert snap["repro_serve_queue_wait_seconds_count"] == 4
+    total = sum(e["dur_us"] for e in by["serve.queue_wait"]) / 1e6
+    assert snap["repro_serve_queue_wait_seconds_sum"] == pytest.approx(
+        total, rel=1e-6)
+    assert snap["repro_serve_request_seconds_count"] == 4
