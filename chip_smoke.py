@@ -135,21 +135,17 @@ def simplex_memory(instances):
     """The compiler's ``memory_analysis`` of the batched simplex program
     the engine runs for these instances' one bucket, compiled for the
     default device (a persistent-cache hit once the bucket has run)."""
-    import jax
-    import jax.numpy as jnp
-
     from repro.engine.arena import InstanceArena
     from repro.engine.batched_lp import build_lp_bucket
-    from repro.engine.batched_simplex import _solve_batch
+    from repro.engine.batched_simplex import _packed_lp_struct, _solve_batch
     from repro.jaxenv import x64
 
     (bucket,) = InstanceArena(instances).buckets
     lp = build_lp_bucket(bucket)
-    shapes = ((bucket.B,) + lp.c.shape, lp.A_ub.shape, lp.b_ub.shape,
-              lp.A_eq.shape, lp.b_eq.shape)
+    n, mu, me = lp.c.shape[0], lp.A_ub.shape[1], lp.A_eq.shape[1]
     with x64():
         return _solve_batch.lower(
-            *(jax.ShapeDtypeStruct(s, jnp.float64) for s in shapes),
+            _packed_lp_struct(bucket.B, n, mu, me), n, mu, me,
             20_000,  # solve_simplex_batched's default iteration cap
         ).compile().memory_analysis()
 

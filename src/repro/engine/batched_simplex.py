@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.jaxenv import x64
+from repro.obs import metrics as obs_metrics
 from repro.obs.trace import span
 from repro.pivoting import harris_row
 
@@ -374,11 +375,69 @@ def _solve_one(c, A_ub, b_ub, A_eq, b_eq, max_iter):
                         st1, st2, it1, it2, n=n, dummy=dummy)
 
 
-@partial(jax.jit, static_argnums=(5,))
-def _solve_batch(c, A_ub, b_ub, A_eq, b_eq, max_iter):
-    return jax.vmap(_solve_one, in_axes=(0, 0, 0, 0, 0, None))(
-        c, A_ub, b_ub, A_eq, b_eq, max_iter
-    )
+# ---------------------------------------------------------------------------
+# The host<->device boundary of one bucket
+#
+# A cold bucket crosses to the device as one float64 buffer and back as
+# one: each crossing waits for the device and then for the GIL, which the
+# server's handler threads hold in turn, so their count costs more than
+# their bytes.  In: every lane's [c | A_ub | b_ub | A_eq | b_eq], row-major.
+# Out: [x | objective | status, iterations, phase 1, phase 2 | basis]; every
+# count and basis id is exact in float64.  Not uint32 words: the TPU keeps
+# float64 in an emulated form, and its bitcast from words rounds otherwise
+# than its transfer of float64 does (plans would change), while the chip's
+# compiler lowers no bitcast from float64 to words at all.
+# ---------------------------------------------------------------------------
+
+
+def _pack_lp(c, A_ub, b_ub, A_eq, b_eq) -> np.ndarray:
+    """The bucket's LPs as one ``[B, lp_width]`` float64 buffer."""
+    B = c.shape[0]
+    return np.concatenate([a.reshape(B, -1) for a in (c, A_ub, b_ub, A_eq, b_eq)],
+                          axis=1)
+
+
+def _packed_lp_struct(B, n, m_ub, m_eq, sharding=None):
+    """The shape and dtype of ``_pack_lp``'s buffer, for lowering
+    ``_solve_batch`` without the arrays."""
+    width = n + m_ub * n + m_ub + m_eq * n + m_eq
+    return jax.ShapeDtypeStruct((B, width), jnp.float64, sharding=sharding)
+
+
+def _unpack_lp(lp, n, m_ub, m_eq):
+    """In the program: ``_pack_lp``'s buffer back into (c, A_ub, b_ub, A_eq,
+    b_eq) by static slices."""
+    B = lp.shape[0]
+    parts, off = [], 0
+    for shape in ((n,), (m_ub, n), (m_ub,), (m_eq, n), (m_eq,)):
+        size = int(np.prod(shape))
+        parts.append(lp[:, off:off + size].reshape((B,) + shape))
+        off += size
+    return parts
+
+
+def _pack_result(x, obj, status, iters, it1, it2, basis):
+    """In the program: the extracted results as one ``[B, n + 5 + m_rows]``
+    float64 buffer."""
+    counts = jnp.stack([status, iters, it1, it2], axis=1)
+    return jnp.concatenate([x, obj[:, None], counts.astype(jnp.float64),
+                            basis.astype(jnp.float64)], axis=1)
+
+
+def _unpack_result(out: np.ndarray, n: int):
+    """On the host: ``_pack_result``'s buffer as (x, objective, status,
+    iterations, phase-1 and phase-2 iterations, basis), numpy views and
+    exact integer casts."""
+    counts = out[:, n + 1:n + 5].astype(np.int32)
+    return (out[:, :n], out[:, n], counts[:, 0], counts[:, 1], counts[:, 2],
+            counts[:, 3], out[:, n + 5:].astype(np.int64))
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _solve_batch(lp, n, m_ub, m_eq, max_iter):
+    """The served program: one packed bucket in, one packed result out."""
+    return _pack_result(*jax.vmap(_solve_one, in_axes=(0, 0, 0, 0, 0, None))(
+        *_unpack_lp(lp, n, m_ub, m_eq), max_iter))
 
 
 def _phase_stack(T, basis, ncols_price, max_iter, bland_after, interpret):
@@ -414,8 +473,8 @@ def _phase_stack(T, basis, ncols_price, max_iter, bland_after, interpret):
     return T, basis, it, status
 
 
-@partial(jax.jit, static_argnums=(5, 6))
-def _solve_batch_pallas(c, A_ub, b_ub, A_eq, b_eq, max_iter, interpret):
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _solve_batch_pallas(lp, n, m_ub, m_eq, max_iter, interpret):
     """The *masked* fused-kernel twin of ``_solve_batch``: identical setup,
     inter-phase bookkeeping, and extraction (shared, vmapped), with both
     pivot phases run by the Pallas kernel over the stacked tableaux.  The
@@ -423,21 +482,19 @@ def _solve_batch_pallas(c, A_ub, b_ub, A_eq, b_eq, max_iter, interpret):
     production Pallas path; this monolith stays as its parity reference —
     every lane's pivots are position-independent, so the two are
     bit-identical (tests/test_hotpath.py)."""
-    n = c.shape[1]
-    m_ub, m_eq = A_ub.shape[1], A_eq.shape[1]
     m_rows = m_ub + m_eq
     dummy = n + m_ub
     bland_after = max(200, 4 * (m_rows + 1))
 
-    T, basis, c_s, col_scale = jax.vmap(_setup_one)(c, A_ub, b_ub, A_eq, b_eq)
+    T, basis, c_s, col_scale, c = _setup_packed(lp, n, m_ub, m_eq)
     T, basis, it1, st1 = _phase_stack(
         T, basis, dummy, max_iter, bland_after, interpret)
     T, basis, infeasible, drivable = jax.vmap(
         partial(_between_phases, n=n, dummy=dummy))(T, basis, st1, c_s)
     T, basis, it2, st2 = _phase_stack(
         T, basis, dummy, max_iter, bland_after, interpret)
-    return jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
-        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2)
+    return _pack_result(*jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
+        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2))
 
 
 # ---------------------------------------------------------------------------
@@ -455,7 +512,14 @@ def _solve_batch_pallas(c, A_ub, b_ub, A_eq, b_eq, max_iter, interpret):
 # so compacted results are bit-identical to the masked driver's.
 # ---------------------------------------------------------------------------
 
-_setup_batch = jax.jit(jax.vmap(_setup_one))
+def _setup_packed(lp, n, m_ub, m_eq):
+    """Unpack the bucket and set every lane up; also returns the unscaled
+    objective rows the extraction prices ``x`` with."""
+    c, A_ub, b_ub, A_eq, b_eq = _unpack_lp(lp, n, m_ub, m_eq)
+    return (*jax.vmap(_setup_one)(c, A_ub, b_ub, A_eq, b_eq), c)
+
+
+_setup_batch = jax.jit(_setup_packed, static_argnums=(1, 2, 3))
 
 
 @partial(jax.jit, static_argnames=("n", "dummy"))
@@ -467,8 +531,8 @@ def _between_batch(T, basis, st1, c_s, *, n, dummy):
 @partial(jax.jit, static_argnames=("n", "dummy"))
 def _extract_batch(T, basis, col_scale, c, infeasible, drivable,
                    st1, st2, it1, it2, *, n, dummy):
-    return jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
-        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2)
+    return _pack_result(*jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
+        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2))
 
 
 @partial(jax.jit, static_argnames=(
@@ -543,8 +607,7 @@ def _phase_compact(T, basis, ncols_price, max_iter, bland_after, interpret,
     return Th, bh, ith, sth
 
 
-def _solve_batch_pallas_compact(c, A_ub, b_ub, A_eq, b_eq, max_iter,
-                                interpret):
+def _solve_batch_pallas_compact(lp, n, m_ub, m_eq, max_iter, interpret):
     """Host-level compaction-epoch driver around the fused K-pivot kernel.
 
     Setup, inter-phase bookkeeping, and extraction are the same jitted
@@ -553,8 +616,6 @@ def _solve_batch_pallas_compact(c, A_ub, b_ub, A_eq, b_eq, max_iter,
     """
     from repro.engine.autotune import pivot_schedule
 
-    n = c.shape[1]
-    m_ub, m_eq = A_ub.shape[1], A_eq.shape[1]
     m_rows = m_ub + m_eq
     dummy = n + m_ub
     bland_after = max(200, 4 * (m_rows + 1))
@@ -562,7 +623,7 @@ def _solve_batch_pallas_compact(c, A_ub, b_ub, A_eq, b_eq, max_iter,
     tune = pivot_schedule(m_rows + 1, dummy + 2, interpret)
     kp, nl = tune["k_pivots"], tune["n_launches"]
 
-    T, basis, c_s, col_scale = _setup_batch(c, A_ub, b_ub, A_eq, b_eq)
+    T, basis, c_s, col_scale, c = _setup_batch(lp, n, m_ub, m_eq)
     T, basis, it1, st1 = _phase_compact(
         T, basis, dummy, max_iter, bland_after, interpret, kp, nl)
     T, basis, infeasible, drivable = _between_batch(
@@ -621,6 +682,16 @@ def solve_simplex_batched(
     Arguments are batched along axis 0: c [B, n], A_ub [B, mu, n], b_ub
     [B, mu], A_eq [B, me, n], b_eq [B, me]; pass None for absent families.
 
+    The lanes solved cold cross to the device once and back once, whatever
+    the driver: every lane's LP goes in as one float64 buffer (``_pack_lp``),
+    the driver's one program slices it apart, and its results come back as
+    one float64 buffer that the host splits into the result's arrays
+    (``_unpack_result``).  Values are bit-identical to passing and fetching
+    each array on its own.  The
+    crossings are counted in ``repro_simplex_transfers_total`` and their
+    bytes in ``repro_simplex_transfer_bytes_total``, both by ``direction``
+    (``to_device``, ``to_host``); a call served wholly warm makes none.
+
     ``use_pallas=True`` runs both pivot phases through the fused K-pivot
     Pallas kernel (repro.kernels.simplex_pivot) over the stacked tableaux;
     results are identical (parity-tested) — setup, inter-phase bookkeeping,
@@ -653,9 +724,6 @@ def solve_simplex_batched(
     if A_ub.shape[0] != B or A_eq.shape[0] != B:
         raise ValueError("batch dims disagree")
     m_rows = A_ub.shape[1] + A_eq.shape[1]
-    # numpy args go straight into the jitted calls (their argument machinery
-    # batches host->device transfers; explicit per-array jnp.asarray costs
-    # ~100us per array and was a measurable share of small-bucket solves)
     with x64():
         x = np.empty((B, n))
         obj = np.empty(B)
@@ -699,36 +767,41 @@ def solve_simplex_batched(
                     cold_idx = np.flatnonzero(cold_mask)
 
         if cold_idx.size:
-            sub = cold_idx.size < B
-            ci, Aui, bui = (c[cold_idx], A_ub[cold_idx], b_ub[cold_idx]) if sub \
-                else (c, A_ub, b_ub)
-            Aei, bei = (A_eq[cold_idx], b_eq[cold_idx]) if sub else (A_eq, b_eq)
-            # host spans: the call (argument transfer and dispatch; the
-            # device runs on asynchronously), the fetch of its outputs
-            # (which waits for the device), and the host's feasibility pass
-            with span("simplex.dispatch", B=len(cold_idx)):
+            met = obs_metrics.get_registry()
+            n_cold = len(cold_idx)
+            m_ub, m_eq = A_ub.shape[1], A_eq.shape[1]
+            # host spans: packing and the call (argument transfer and
+            # dispatch; the device runs on asynchronously), the fetch of the
+            # packed result (which waits for the device), and the host's
+            # feasibility pass
+            with span("simplex.dispatch", B=n_cold):
+                lp = _pack_lp(c, A_ub, b_ub, A_eq, b_eq)
+                if n_cold < B:
+                    lp = lp[cold_idx]
                 if use_pallas and m_rows > 0:
                     from repro.kernels.ops import _interp  # the kernels' TPU gate
 
                     cc = compact
                     if cc is None:
-                        cc = len(cold_idx) >= 2  # epochs need lanes to retire
+                        cc = n_cold >= 2  # epochs need lanes to retire
                     driver = (_solve_batch_pallas_compact if cc
                               else _solve_batch_pallas)
-                    out = driver(ci, Aui, bui, Aei, bei, int(max_iter),
+                    out = driver(lp, n, m_ub, m_eq, int(max_iter),
                                  _interp(interpret))
                 else:
-                    out = _solve_batch(ci, Aui, bui, Aei, bei, int(max_iter))
-            with span("simplex.fetch", B=len(cold_idx)):
-                cx, cobj, cst, cit, cit1, cit2, cbasis = out
-                x[cold_idx] = np.asarray(cx)
-                obj[cold_idx] = np.asarray(cobj)
-                status[cold_idx] = np.asarray(cst)
-                iters[cold_idx] = np.asarray(cit)
-                it1[cold_idx] = np.asarray(cit1)
-                it2[cold_idx] = np.asarray(cit2)
+                    out = _solve_batch(lp, n, m_ub, m_eq, int(max_iter))
+            met.inc("repro_simplex_transfers_total", direction="to_device")
+            met.inc("repro_simplex_transfer_bytes_total", lp.nbytes,
+                    direction="to_device")
+            with span("simplex.fetch", B=n_cold):
+                out = np.asarray(out)
+                (x[cold_idx], obj[cold_idx], status[cold_idx], iters[cold_idx],
+                 it1[cold_idx], it2[cold_idx], cbasis) = _unpack_result(out, n)
                 if basis_out is not None:
-                    basis_out[cold_idx] = np.asarray(cbasis)
+                    basis_out[cold_idx] = cbasis
+            met.inc("repro_simplex_transfers_total", direction="to_host")
+            met.inc("repro_simplex_transfer_bytes_total", out.nbytes,
+                    direction="to_host")
 
         with span("simplex.demote", B=B):
             status = _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq)

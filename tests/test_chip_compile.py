@@ -70,7 +70,7 @@ def test_solve_batch_compiles_for_v5e_at_table2_size(one_chip):
     import jax.numpy as jnp
 
     from repro.engine.batched_lp import build_lp_bucket
-    from repro.engine.batched_simplex import _solve_batch
+    from repro.engine.batched_simplex import _packed_lp_struct, _solve_batch
     from repro.jaxenv import x64
 
     lp = build_lp_bucket(_bucket("chain", 50, 0.0))
@@ -78,11 +78,13 @@ def test_solve_batch_compiles_for_v5e_at_table2_size(one_chip):
     tableau = B * (mu + me + 1) * (n + mu + 2) * 8
     assert tableau > 1e9  # the bucket is real work: ~1 GB of f64 tableau
     with x64():
-        S = lambda *s: jax.ShapeDtypeStruct(s, jnp.float64, sharding=one_chip)
         compiled = _solve_batch.lower(
-            S(B, n), S(B, mu, n), S(B, mu), S(B, me, n), S(B, me), 20_000,
+            _packed_lp_struct(B, n, mu, me, sharding=one_chip), n, mu, me,
+            20_000,
         ).compile()
     assert _fits(compiled) > tableau
+    (out,) = jax.tree.leaves(compiled.out_info)  # one packed result
+    assert out.shape == (B, n + 5 + mu + me) and out.dtype == jnp.float64
 
 
 @pytest.mark.parametrize("topology,n_loads,return_ratio", [

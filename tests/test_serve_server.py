@@ -115,8 +115,9 @@ def test_worker_threads_keep_float64_device_outputs(monkeypatch):
     def spy(real, name):
         def wrapped(*args):
             out = real(*args)
+            outs = out if isinstance(out, tuple) else (out,)  # solve: packed
             seen.append((name, threading.current_thread().name,
-                         {str(o.dtype) for o in out if o.dtype.kind == "f"}))
+                         {str(o.dtype) for o in outs if o.dtype.kind == "f"}))
             return out
         return wrapped
 
