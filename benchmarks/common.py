@@ -34,140 +34,3 @@ def rel_stats(rel: np.ndarray) -> tuple:
 
 def banner(title: str):
     print(f"\n=== {title} ===")
-
-
-# --------------------------------------------------------------------------
-# shared three-way (serial / batched / pallas) throughput machinery, used by
-# bench_engine_throughput (chain) and bench_star (star) so the timing
-# methodology and the claims convention exist exactly once
-# --------------------------------------------------------------------------
-
-
-def three_way_solve(insts: list, serial_sample: int) -> tuple[dict, dict]:
-    """inst/s for the serial solve loop vs solve_bulk vs solve_bulk(pallas).
-
-    The serial loop measures a sample and extrapolates (the whole point is
-    that it is too slow to run the full population inside a benchmark
-    budget); the engine paths get one full warm-up call so every (bucket,
-    batch) shape is compiled before timing, as a serving process would
-    reuse compiled shapes across ticks.  Also returns per-path fallback
-    counts (elements whose report came from a different backend).
-    """
-    from repro.core.solver import solve
-    from repro.engine import solve_bulk
-
-    t0 = time.perf_counter()
-    for inst in insts[:serial_sample]:
-        solve(inst, backend="simplex")
-    serial_per = (time.perf_counter() - t0) / serial_sample
-    out = {"serial": 1.0 / serial_per}
-
-    n_fallback = {}
-    for label, use_pallas in (("batched", False), ("pallas", True)):
-        solve_bulk(insts, use_pallas=use_pallas)  # warm-up: compile shapes
-        t0 = time.perf_counter()
-        res = solve_bulk(insts, use_pallas=use_pallas)
-        out[label] = len(insts) / (time.perf_counter() - t0)
-        n_fallback[label] = sum(1 for r in res if r.backend != label)
-    return out, n_fallback
-
-
-def three_way_replay(insts: list, gammas: list) -> dict:
-    """inst/s for the serial ASAP replay vs the vmapped vs the fused kernel."""
-    from repro.core.simulator import simulate
-    from repro.engine import InstanceArena, makespans, simulate_bucket
-
-    t0 = time.perf_counter()
-    for inst, g in zip(insts, gammas):
-        simulate(inst, g)
-    out = {"serial": len(insts) / (time.perf_counter() - t0)}
-
-    for label, use_pallas in (("batched", False), ("pallas", True)):
-        arena = InstanceArena(insts, pad_shapes=True)
-        for bucket in arena.buckets:  # warm-up per shape
-            simulate_bucket(bucket, bucket.gamma_padded(
-                [gammas[i] for i in bucket.indices]), use_pallas=use_pallas)
-        t0 = time.perf_counter()
-        makespans(insts, gammas, use_pallas=use_pallas)
-        out[label] = len(insts) / (time.perf_counter() - t0)
-    return out
-
-
-def speed_proportional_gammas(insts: list) -> list:
-    """Per-instance [m, T] fractions proportional to processor speeds, each
-    load split evenly over its installments (the SIMPLE-heuristic shape the
-    replay sweeps target)."""
-    gammas = []
-    for inst in insts:
-        speeds = 1.0 / inst.platform.w
-        g = np.tile((speeds / speeds.sum())[:, None], (1, inst.total_installments))
-        cells = list(inst.cells())
-        for ln in range(inst.N):
-            cols = [t for t, (l, _) in enumerate(cells) if l == ln]
-            g[:, cols] /= len(cols)
-        gammas.append(g)
-    return gammas
-
-
-def three_way_bench(title: str, solve_insts: list, replay_insts: list,
-                    csv_name: str, quick: bool, solve_note: str = "") -> dict:
-    """The whole three-way throughput bench, once: solve + replay timing,
-    the printed report, the CSV, and the claims.  A bench module supplies
-    only its populations and labels."""
-    banner(title)
-    n = len(solve_insts)
-    solve_ips, n_fallback = three_way_solve(solve_insts, serial_sample=min(32, n))
-    speedup = {k: solve_ips[k] / solve_ips["serial"] for k in ("batched", "pallas")}
-    print(f"  solve:  serial {solve_ips['serial']:8.1f} inst/s   "
-          f"batched {solve_ips['batched']:8.1f} inst/s ({speedup['batched']:.1f}x)   "
-          f"pallas {solve_ips['pallas']:8.1f} inst/s ({speedup['pallas']:.1f}x)   "
-          f"({n} {solve_note}instances, fallbacks {n_fallback})")
-
-    gammas = speed_proportional_gammas(replay_insts)
-    replay_ips = three_way_replay(replay_insts, gammas)
-    replay_speedup = {k: replay_ips[k] / replay_ips["serial"]
-                      for k in ("batched", "pallas")}
-    print(f"  replay: serial {replay_ips['serial']:8.1f} inst/s   "
-          f"batched {replay_ips['batched']:8.1f} inst/s "
-          f"({replay_speedup['batched']:.1f}x)   "
-          f"pallas {replay_ips['pallas']:8.1f} inst/s "
-          f"({replay_speedup['pallas']:.1f}x)")
-
-    write_csv(
-        csv_name,
-        [["solve", solve_ips["serial"], solve_ips["batched"],
-          solve_ips["pallas"], speedup["batched"], speedup["pallas"]],
-         ["replay", replay_ips["serial"], replay_ips["batched"],
-          replay_ips["pallas"], replay_speedup["batched"],
-          replay_speedup["pallas"]]],
-        ["path", "serial_inst_per_sec", "batched_inst_per_sec",
-         "pallas_inst_per_sec", "batched_speedup", "pallas_speedup"],
-    )
-    return throughput_claims(quick, speedup, replay_speedup, solve_ips,
-                             n_fallback)
-
-
-def throughput_claims(quick: bool, speedup: dict, replay_speedup: dict,
-                      solve_ips: dict, n_fallback: dict) -> dict:
-    """The shared claims convention: correctness claims always gate; the 10x
-    speedup bars are full-scale statements (1024/512-instance populations) —
-    a smoke run measures small batches on a possibly-contended CI box,
-    where a ratio of two timings taken at different moments is noise, so
-    quick mode records the ratios informationally instead of gating."""
-    claims = {
-        "no_fallbacks": n_fallback["batched"] == 0,
-        "no_pallas_fallbacks": n_fallback["pallas"] == 0,
-        "pallas_solve_runs": solve_ips["pallas"] > 0.0,
-    }
-    if quick:
-        claims["solve_speedup"] = round(speedup["batched"], 2)
-        claims["replay_speedup"] = round(replay_speedup["batched"], 2)
-    else:
-        claims["solve_10x"] = speedup["batched"] >= 10.0
-        claims["replay_10x"] = replay_speedup["batched"] >= 10.0
-    for k, v in claims.items():
-        if isinstance(v, bool):
-            print(f"  CLAIM {k}: {'OK' if v else 'VIOLATED'}")
-        else:
-            print(f"  CLAIM {k} = {v} (informational at smoke scale)")
-    return claims

@@ -2,229 +2,29 @@
 report.  ``python -m benchmarks.run [--full]`` (quick mode is the default so
 CI stays fast; --full reproduces the paper-scale statistics).
 
-Every run ends by writing ``bench_out/summary.json`` — a schema-versioned,
-git-SHA-stamped merge of every bench CSV in ``bench_out/`` plus the claims
-of this run and the key throughput metrics rendered through the metrics
-registry (DESIGN.md §8).  ``--summary-only`` rebuilds the summary from the
-CSVs already on disk without running any bench (the committed CSVs hold the
-full-scale numbers; a laptop smoke run should not overwrite them just to
-refresh the summary).  ``scripts/check_regression.py`` consumes the summary.
+Each bench writes its CSV or document into ``bench_out/`` and returns its
+claims (the paper's statements it checks); the run prints every claim and
+exits non-zero if one is violated or a bench fails.  These are CPU
+reproductions of the paper's results, not the system's speed: that is
+measured on the chip by ``bench/`` (``BENCHMARK.json``).
 """
 
 from __future__ import annotations
 
 import argparse
-import csv
-import glob
-import json
-import os
-import platform
-import subprocess
 import sys
 import time
-
-from .common import OUT_DIR
-
-SUMMARY_SCHEMA_VERSION = 1
-
-# CSV stem -> (bench label, throughput columns) for the metrics rendering
-_THROUGHPUT_CSVS = {"engine_throughput": "chain", "star_throughput": "star"}
-
-
-def _git_sha() -> str | None:
-    try:
-        out = subprocess.run(
-            ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            timeout=10,
-        )
-        return out.stdout.strip() or None
-    except Exception:
-        return None
-
-
-def _json_safe(v):
-    """Claims dicts mix python/numpy scalars; normalize for json.dump."""
-    import numpy as np
-
-    if isinstance(v, (bool, np.bool_)):
-        return bool(v)
-    if isinstance(v, (int, np.integer)):
-        return int(v)
-    if isinstance(v, (float, np.floating)):
-        return float(v)
-    return v
-
-
-def collect_benches(out_dir: str = OUT_DIR) -> dict:
-    """Every bench CSV in ``out_dir`` as {stem: {header, rows}}."""
-    benches = {}
-    for path in sorted(glob.glob(os.path.join(out_dir, "*.csv"))):
-        stem = os.path.splitext(os.path.basename(path))[0]
-        with open(path, newline="") as f:
-            rows = list(csv.reader(f))
-        if rows:
-            benches[stem] = {"header": rows[0], "rows": rows[1:]}
-    return benches
-
-
-def bench_metrics(benches: dict) -> dict:
-    """Render the key throughput numbers through a metrics registry.
-
-    The summary's ``metrics`` section IS a registry snapshot — the same
-    ``name{label=value}`` key schema the live process exports, so the
-    regression gate and a Prometheus scrape read identical names.
-    """
-    from repro.obs.metrics import MetricsRegistry
-
-    reg = MetricsRegistry()
-    for stem, bench in _THROUGHPUT_CSVS.items():
-        b = benches.get(stem)
-        if not b:
-            continue
-        for row in b["rows"]:
-            rec = dict(zip(b["header"], row))
-            for path in ("serial", "batched", "pallas"):
-                reg.set_gauge("repro_bench_inst_per_sec",
-                              float(rec[f"{path}_inst_per_sec"]),
-                              bench=bench, op=rec["path"], path=path)
-    b = benches.get("session_throughput")
-    if b:
-        for row in b["rows"]:
-            rec = dict(zip(b["header"], row))
-            reg.set_gauge("repro_bench_session_inst_per_sec",
-                          float(rec["session_inst_per_sec"]), mix=rec["mix"])
-            reg.set_gauge("repro_bench_session_to_direct_ratio",
-                          float(rec["session_to_direct_ratio"]), mix=rec["mix"])
-    b = benches.get("hotpath")
-    if b:
-        for row in b["rows"]:
-            rec = dict(zip(b["header"], row))
-            if rec["metric"] == "keys_per_sec":
-                reg.set_gauge("repro_bench_keys_per_sec",
-                              float(rec["value"]), path=rec["label"])
-            elif rec["metric"] == "warm_hit_inst_per_sec":
-                reg.set_gauge("repro_bench_warm_hit_inst_per_sec",
-                              float(rec["value"]), path=rec["label"])
-            elif rec["metric"] == "session_to_direct_ratio":
-                reg.set_gauge("repro_bench_session_to_direct_ratio",
-                              float(rec["value"]), mix=f"hotpath_{rec['label']}")
-    b = benches.get("replan")
-    if b:
-        for row in b["rows"]:
-            rec = dict(zip(b["header"], row))
-            if rec["metric"] == "replan_solve_per_sec":
-                reg.set_gauge("repro_bench_replan_solves_per_sec",
-                              float(rec["value"]), path=rec["label"])
-            elif rec["metric"] == "replan_warm_speedup":
-                reg.set_gauge("repro_bench_replan_warm_speedup",
-                              float(rec["value"]), layer=rec["label"])
-            elif rec["metric"] == "replan_event_per_sec":
-                reg.set_gauge("repro_bench_replan_events_per_sec",
-                              float(rec["value"]), path=rec["label"])
-    b = benches.get("serve")
-    if b:
-        # only the warm-restart rows are gated; the shard throughput rows
-        # stay CSV-only (a 1-device "scaling" ratio is contention noise)
-        for row in b["rows"]:
-            rec = dict(zip(b["header"], row))
-            if rec["metric"] == "serve_inst_per_sec":
-                reg.set_gauge("repro_bench_serve_inst_per_sec",
-                              float(rec["value"]), path=rec["label"])
-            elif rec["metric"] == "serve_warm_restart_ratio":
-                reg.set_gauge("repro_bench_serve_warm_restart_ratio",
-                              float(rec["value"]), layer=rec["label"])
-    return reg.snapshot()
-
-
-def build_summary(claims: dict, failures: list, elapsed_s: float,
-                  quick: bool | None) -> dict:
-    benches = collect_benches()
-    return {
-        "schema_version": SUMMARY_SCHEMA_VERSION,
-        "git_sha": _git_sha(),
-        "created_unix": time.time(),
-        "host": {"platform": platform.platform(),
-                 "python": platform.python_version()},
-        "quick": quick,  # None: --summary-only (no bench ran in this process)
-        "elapsed_s": elapsed_s,
-        "claims": {k: _json_safe(v) for k, v in claims.items()},
-        "failures": [{"bench": n, "error": e} for n, e in failures],
-        "benches": benches,
-        "metrics": bench_metrics(benches),
-    }
-
-
-def validate_summary(d: dict) -> list:
-    """Schema check for summary.json; returns a list of problems (empty = ok)."""
-    errs = []
-    if d.get("schema_version") != SUMMARY_SCHEMA_VERSION:
-        errs.append(f"schema_version: want {SUMMARY_SCHEMA_VERSION}, "
-                    f"got {d.get('schema_version')!r}")
-    for key, typ in (("host", dict), ("claims", dict), ("failures", list),
-                     ("benches", dict), ("metrics", dict)):
-        if not isinstance(d.get(key), typ):
-            errs.append(f"{key}: want {typ.__name__}, got {type(d.get(key)).__name__}")
-    if not isinstance(d.get("created_unix"), (int, float)):
-        errs.append("created_unix: want a unix timestamp")
-    if d.get("git_sha") is not None and (
-        not isinstance(d["git_sha"], str) or len(d["git_sha"]) < 7
-    ):
-        errs.append(f"git_sha: want null or a >=7-char sha, got {d['git_sha']!r}")
-    for stem, b in (d.get("benches") or {}).items():
-        if not isinstance(b, dict) or "header" not in b or "rows" not in b:
-            errs.append(f"benches[{stem}]: want {{header, rows}}")
-            continue
-        w = len(b["header"])
-        if any(len(r) != w for r in b["rows"]):
-            errs.append(f"benches[{stem}]: ragged rows (header width {w})")
-    for k, v in (d.get("metrics") or {}).items():
-        if not isinstance(k, str) or not isinstance(v, (int, float)):
-            errs.append(f"metrics[{k!r}]: want str -> number")
-    return errs
-
-
-def write_summary(claims: dict, failures: list, elapsed_s: float,
-                  quick: bool | None) -> str:
-    summary = build_summary(claims, failures, elapsed_s, quick)
-    errs = validate_summary(summary)
-    if errs:  # never ship a summary the CI validator would reject
-        raise AssertionError(f"summary.json failed its own schema: {errs}")
-    os.makedirs(OUT_DIR, exist_ok=True)
-    path = os.path.join(OUT_DIR, "summary.json")
-    with open(path, "w") as f:
-        json.dump(summary, f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(f"wrote {path} ({len(summary['benches'])} benches, "
-          f"{len(summary['metrics'])} metrics)")
-    return path
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true", help="paper-scale instance counts")
     ap.add_argument("--only", default=None, help="comma-separated bench names")
-    ap.add_argument("--smoke", action="store_true",
-                    help="CI gate: quick mode over the engine-facing benches "
-                         "(three-way engine throughput + kernels) unless "
-                         "--only narrows it further")
-    ap.add_argument("--summary-only", action="store_true",
-                    help="rebuild bench_out/summary.json from the CSVs "
-                         "already on disk; runs no bench")
     args = ap.parse_args(argv)
-    if args.smoke and args.full:
-        ap.error("--smoke and --full are mutually exclusive")
-    if args.summary_only:
-        write_summary({}, [], 0.0, quick=None)
-        return 0
     quick = not args.full
-    if args.smoke and not args.only:
-        args.only = "engine_throughput,star,kernels,session,hotpath,replan,serve"
 
-    from . import (bench_campaign, bench_engine_throughput, bench_hotpath,
-                   bench_kernels, bench_latency_qstar, bench_lp_scaling,
-                   bench_motivating_example, bench_replan, bench_serve,
-                   bench_session, bench_star, bench_table2, bench_theorem1,
+    from . import (bench_campaign, bench_latency_qstar, bench_lp_scaling,
+                   bench_motivating_example, bench_table2, bench_theorem1,
                    roofline)
 
     benches = {
@@ -233,15 +33,8 @@ def main(argv=None) -> int:
         "theorem1": bench_theorem1.main,
         "latency_qstar": bench_latency_qstar.main,
         "lp_scaling": bench_lp_scaling.main,
-        "kernels": bench_kernels.main,
-        "engine_throughput": bench_engine_throughput.main,
-        "star": bench_star.main,
-        "session": bench_session.main,
-        "hotpath": bench_hotpath.main,
-        "replan": bench_replan.main,
-        "serve": bench_serve.main,
-        # not in the --smoke only-list: CI gives the campaign its own
-        # dedicated step (python -m repro.eval --smoke + check_campaign.py)
+        # CI gives the campaign its own dedicated step
+        # (python -m repro.eval --smoke + check_campaign.py)
         "campaign": bench_campaign.main,
         "roofline_single": lambda quick: roofline.main(quick, mesh="single"),
         "roofline_multi": lambda quick: roofline.main(quick, mesh="multi"),
@@ -277,7 +70,6 @@ def main(argv=None) -> int:
         print(f"  ERR {name}: {err}")
     print(f"{len(all_claims) - len(bad)}/{len(all_claims)} claims OK, "
           f"{len(failures)} bench errors")
-    write_summary(all_claims, failures, elapsed, quick)
     return 1 if (bad or failures) else 0
 
 

@@ -63,7 +63,7 @@ print(f"LP (Fig. 6, Q=2 installments): makespan = {art.makespan:.6f}"
       f"  (paper's hand schedule: 781/653 * 3/4 = {781 / 653 * 0.75:.6f})")
 
 print(f"registered solver backends: {available_backends()}")
-for backend in ("simplex", "pallas"):
+for backend in ("simplex", "batched"):
     a = session.solve(paper, Policy(installments=2, backend=backend))
     print(f"{backend:>8} backend agrees: makespan = {a.makespan:.6f} "
           f"(served by {a.backend}, status={a.status})")

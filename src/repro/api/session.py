@@ -15,7 +15,7 @@ entry points (``Planner.plan*``, ``PlanService``, ``ChainReplanner``,
   :class:`PlanTicket` and the session coalesces tickets into micro-batches:
   a flush fires when the queue reaches ``max_batch``, when a submitted
   deadline expires, or when any ticket's ``result()`` is demanded.  Serving
-  traffic therefore batches itself into the vmapped/Pallas engine instead
+  traffic therefore batches itself into the vmapped engine instead
   of relying on callers to hand-assemble buckets.
 
 Synchronous paths: ``solve(problem)`` for one plan, ``solve_bulk(problems)``
@@ -62,7 +62,7 @@ __all__ = ["Session", "PlanTicket", "PlanSubscription"]
 
 # backends that consult the session's solution cache; resolved lazily so the
 # cache (and with it the engine) is only constructed when actually needed
-_ENGINE_BACKENDS = ("batched", "pallas")
+_ENGINE_BACKENDS = ("batched",)
 
 # the serial-solver family: a bulk engine backend landing on one of these
 # labels means the batched path handed the element to the per-instance

@@ -17,10 +17,7 @@ Built-in backends:
                simplex if scipy is unavailable);
   "serial"   — alias of "auto" (the bulk-path name for "loop per instance");
   "batched"  — the JAX engine (repro.engine.service.BatchedBackend),
-               registered lazily so importing repro.core never imports jax;
-  "pallas"   — the same engine with its hot loops in fused Pallas kernels
-               (repro.kernels.simplex_pivot / asap_replay); selecting it
-               raises where the kernels cannot run.
+               registered lazily so importing repro.core never imports jax.
 
 Every optimal solve is finished by an ASAP *replay* of the LP's fractions
 through the simulator: the replay is guaranteed feasible, its makespan can
@@ -369,15 +366,8 @@ def _batched_factory(cache=None):
     return BatchedBackend(cache=cache)
 
 
-def _pallas_factory(cache=None):
-    from repro.engine.service import PallasBackend  # deferred: jax import
-
-    return PallasBackend(cache=cache)
-
-
 register_backend("simplex", SimplexBackend)
 register_backend("scipy", ScipyBackend)
 register_backend("auto", AutoBackend)
 register_backend("serial", AutoBackend)  # bulk-path alias: loop of auto solves
 register_backend("batched", _batched_factory)
-register_backend("pallas", _pallas_factory)

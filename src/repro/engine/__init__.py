@@ -21,14 +21,13 @@ from .arena import InstanceArena, PackedBucket, pack_instances
 from .batched_sim import makespans, simulate_bucket, simulate_many
 from .batched_simplex import STATUS, BatchedSimplexResult, solve_simplex_batched
 from .cache import CachedSolution, SolutionCache, instance_key
-from .service import BatchedBackend, PallasBackend, PlanService, solve_bulk
+from .service import BatchedBackend, PlanService, solve_bulk
 
 __all__ = [
     "InstanceArena",
     "PackedBucket",
     "pack_instances",
     "BatchedBackend",
-    "PallasBackend",
     "simulate_bucket",
     "simulate_many",
     "makespans",

@@ -231,8 +231,7 @@ def _sim_batch(w_cell, z, latency, tau, vcomm, vcomp, rel, retr, valid, gamma,
       topology, with_ret)
 
 
-def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray,
-                    use_pallas: bool = False):
+def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray):
     """ASAP-replay a [B, m, T] fraction batch.
 
     Always returns the fixed 7-slot shape ``(cs, ce, ps, pe, rs, re, mk)``;
@@ -242,11 +241,6 @@ def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray,
     ``gamma`` must already be padded to the bucket shape (see
     :meth:`PackedBucket.gamma_padded`); returned arrays are bucket-shaped —
     use :meth:`PackedBucket.unpad` to strip padding.
-
-    ``use_pallas=True`` runs the whole recurrence in the fused replay kernel
-    (repro.kernels.asap_replay) — one launch per bucket, everything
-    block-resident; results are parity-identical.  The linkless ``m == 1``
-    chain keeps the vmapped path (there is nothing to fuse).
     """
     # numpy args go straight into the jitted call: its argument machinery
     # batches the host->device transfers, where a per-array ``jnp.asarray``
@@ -262,22 +256,15 @@ def simulate_bucket(bucket: PackedBucket, gamma: np.ndarray,
         g = np.asarray(gamma, dtype=np.float64)
         # host spans, as in the simplex: the call, then the fetch that waits
         with span("replay.dispatch", B=g.shape[0]):
-            if use_pallas and bucket.m >= 2:
-                from repro.kernels.ops import asap_replay  # deferred kernel import
-
-                out = asap_replay(*args, valid, g, retr if with_ret else None,
-                                  topology=bucket.topology)
-            else:
-                out = _sim_batch(*args, retr, valid, g, bucket.topology, with_ret)
+            out = _sim_batch(*args, retr, valid, g, bucket.topology, with_ret)
         with span("replay.fetch", B=g.shape[0]):
             out = tuple(np.asarray(o) for o in out)
-        if not with_ret:  # normalize the 5-slot kernel output to 7 slots
+        if not with_ret:  # the 5 slots of a replay without returns, as 7
             out = out[:4] + (None, None) + out[4:]
         return out
 
 
-def simulate_many(instances: list, gammas: list, pad_shapes: bool = True,
-                  use_pallas: bool = False) -> list:
+def simulate_many(instances: list, gammas: list, pad_shapes: bool = True) -> list:
     """Batched counterpart of ``[simulate(i, g) for i, g in zip(...)]``.
 
     Returns a list of :class:`repro.core.schedule.Schedule` in caller order;
@@ -289,7 +276,7 @@ def simulate_many(instances: list, gammas: list, pad_shapes: bool = True,
     results = []
     for bucket in arena.buckets:
         g = bucket.gamma_padded([gammas[i] for i in bucket.indices])
-        cs, ce, ps, pe, rs, re, mk = simulate_bucket(bucket, g, use_pallas=use_pallas)
+        cs, ce, ps, pe, rs, re, mk = simulate_bucket(bucket, g)
         if rs is not None:
             rs, re = bucket.unpad(rs), bucket.unpad(re)
         cs, ce = bucket.unpad(cs), bucket.unpad(ce)
@@ -312,13 +299,12 @@ def simulate_many(instances: list, gammas: list, pad_shapes: bool = True,
     return arena.scatter(results)
 
 
-def makespans(instances: list, gammas: list, pad_shapes: bool = True,
-              use_pallas: bool = False) -> np.ndarray:
+def makespans(instances: list, gammas: list, pad_shapes: bool = True) -> np.ndarray:
     """Just the achieved makespans, [len(instances)] — the sweep fast path."""
     arena = InstanceArena(instances, pad_shapes=pad_shapes)
     per_bucket = []
     for bucket in arena.buckets:
         g = bucket.gamma_padded([gammas[i] for i in bucket.indices])
-        *_, mk = simulate_bucket(bucket, g, use_pallas=use_pallas)
+        *_, mk = simulate_bucket(bucket, g)
         per_bucket.append(list(np.asarray(mk)))
     return np.array(arena.scatter(per_bucket), dtype=np.float64)

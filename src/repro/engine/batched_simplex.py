@@ -26,7 +26,7 @@ Fixed-shape reformulation (everything static so ``vmap``/``jit`` apply):
     batch elements automatically;
   * pricing is Dantzig with a Bland fallback after ``max(200, 4 rows)``
     iterations (anti-cycling); the ratio test is Harris's two-pass rule
-    with a relative pivot threshold (:func:`repro.pivoting.harris_row`), which keeps
+    with a relative pivot threshold (:func:`harris_row`), which keeps
     paper-size LPs (thousands of rows and pivots) from cycling or drifting
     off the polytope.
 
@@ -47,7 +47,6 @@ from jax import lax
 from repro.jaxenv import x64
 from repro.obs import metrics as obs_metrics
 from repro.obs.trace import span
-from repro.pivoting import harris_row
 
 __all__ = ["BatchedSimplexResult", "solve_simplex_batched", "STATUS"]
 
@@ -136,6 +135,34 @@ def _fused_pivot(T, row, col, do_pivot):
     is_col = jnp.arange(T.shape[1]) == col
     return jnp.where(do_pivot & is_col[None, :],
                      is_row[:, None].astype(T.dtype), T)
+
+
+def harris_row(colvals, rhs, basis, bland):
+    """Harris two-pass ratio test over the entering column; (row, unbounded).
+
+    A pivot must exceed ``_EPS`` times the column's largest magnitude (the
+    absolute ``_EPS`` alone where nothing does), so no near-zero pivot
+    amplifies the tableau's rounding.  Pass one takes the longest step that
+    keeps every basic variable above ``-_EPS``; pass two picks, among the
+    rows whose ratio fits in that step, the largest pivot — or, once
+    ``bland`` holds, the smallest basis index (the anti-cycling rule).
+    On paper-size schedule LPs (Table-2 chains, ~2,200 rows, ~3,500
+    pivots) the exact min-ratio rule cycled to the iteration cap or lost
+    feasibility on some lanes, even in IEEE float64.
+    """
+    big = jnp.max(jnp.abs(colvals), initial=0.0)
+    pos = colvals > _EPS * jnp.maximum(1.0, big)
+    pos = jnp.where(jnp.any(pos), pos, colvals > _EPS)
+    safe = jnp.where(pos, colvals, 1.0)
+    step = jnp.min(jnp.where(pos, (jnp.maximum(rhs, 0.0) + _EPS) / safe, jnp.inf),
+                   initial=jnp.inf)
+    fits = pos & (rhs / safe <= step)
+    row = jnp.where(
+        bland,
+        jnp.argmin(jnp.where(fits, basis, jnp.iinfo(jnp.int32).max)),
+        jnp.argmax(jnp.where(fits, colvals, -jnp.inf)),
+    )
+    return row, ~jnp.isfinite(step)
 
 
 def _phase(T, basis, ncols_price, max_iter, bland_after):
@@ -440,201 +467,6 @@ def _solve_batch(lp, n, m_ub, m_eq, max_iter):
         *_unpack_lp(lp, n, m_ub, m_eq), max_iter))
 
 
-def _phase_stack(T, basis, ncols_price, max_iter, bland_after, interpret):
-    """The Pallas phase driver: one fused pivot kernel per iteration over the
-    whole [B, R, C] stack, looping until every element is done.
-
-    Semantically identical to ``jax.vmap(_phase)``: the while_loop's batching
-    rule masks finished lanes there; here the kernel masks them via the
-    in-kernel ``active`` predicate (their rank-1 update is zeroed wholesale).
-    """
-    from repro.kernels.ops import simplex_pivot  # deferred: keep the vmapped
-
-    # path importable without the kernels package
-
-    B = T.shape[0]
-    status = jnp.full((B,), _RUNNING, jnp.int32)
-
-    def cond(carry):
-        _, _, it, status = carry
-        return jnp.any((status == _RUNNING) & (it < max_iter))
-
-    def body(carry):
-        T, basis, it, status = carry
-        return tuple(simplex_pivot(
-            T, basis, it, status, ncols_price=ncols_price,
-            bland_after=bland_after, max_iter=max_iter, interpret=interpret,
-        ))
-
-    T, basis, it, status = lax.while_loop(
-        cond, body, (T, basis, jnp.zeros((B,), jnp.int32), status)
-    )
-    status = jnp.where(status == _RUNNING, jnp.int32(_ITER_LIMIT), status)
-    return T, basis, it, status
-
-
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
-def _solve_batch_pallas(lp, n, m_ub, m_eq, max_iter, interpret):
-    """The *masked* fused-kernel twin of ``_solve_batch``: identical setup,
-    inter-phase bookkeeping, and extraction (shared, vmapped), with both
-    pivot phases run by the Pallas kernel over the stacked tableaux.  The
-    compaction-epoch driver (``_solve_batch_pallas_compact``) is the
-    production Pallas path; this monolith stays as its parity reference —
-    every lane's pivots are position-independent, so the two are
-    bit-identical (tests/test_hotpath.py)."""
-    m_rows = m_ub + m_eq
-    dummy = n + m_ub
-    bland_after = max(200, 4 * (m_rows + 1))
-
-    T, basis, c_s, col_scale, c = _setup_packed(lp, n, m_ub, m_eq)
-    T, basis, it1, st1 = _phase_stack(
-        T, basis, dummy, max_iter, bland_after, interpret)
-    T, basis, infeasible, drivable = jax.vmap(
-        partial(_between_phases, n=n, dummy=dummy))(T, basis, st1, c_s)
-    T, basis, it2, st2 = _phase_stack(
-        T, basis, dummy, max_iter, bland_after, interpret)
-    return _pack_result(*jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
-        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2))
-
-
-# ---------------------------------------------------------------------------
-# Compaction-epoch Pallas driver
-#
-# The masked driver above pays for its laggards twice: every kernel launch
-# moves the *whole* [B, R, C] stack through the grid even when most lanes
-# have converged, and the while_loop runs until the globally slowest lane
-# finishes.  The compaction driver splits each phase into *epochs*: a bounded
-# burst of fused K-pivot launches (one jitted while_loop segment), then a
-# host-side pass that retires finished lanes into result buffers and gathers
-# the still-active ones into a dense prefix, padded up to a power-of-two
-# rung so the epoch kernel compiles once per rung instead of once per active
-# count.  Lane math is position-independent (grid=(B,) one lane per step),
-# so compacted results are bit-identical to the masked driver's.
-# ---------------------------------------------------------------------------
-
-def _setup_packed(lp, n, m_ub, m_eq):
-    """Unpack the bucket and set every lane up; also returns the unscaled
-    objective rows the extraction prices ``x`` with."""
-    c, A_ub, b_ub, A_eq, b_eq = _unpack_lp(lp, n, m_ub, m_eq)
-    return (*jax.vmap(_setup_one)(c, A_ub, b_ub, A_eq, b_eq), c)
-
-
-_setup_batch = jax.jit(_setup_packed, static_argnums=(1, 2, 3))
-
-
-@partial(jax.jit, static_argnames=("n", "dummy"))
-def _between_batch(T, basis, st1, c_s, *, n, dummy):
-    return jax.vmap(partial(_between_phases, n=n, dummy=dummy))(
-        T, basis, st1, c_s)
-
-
-@partial(jax.jit, static_argnames=("n", "dummy"))
-def _extract_batch(T, basis, col_scale, c, infeasible, drivable,
-                   st1, st2, it1, it2, *, n, dummy):
-    return _pack_result(*jax.vmap(partial(_extract_one, n=n, dummy=dummy))(
-        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2))
-
-
-@partial(jax.jit, static_argnames=(
-    "ncols_price", "max_iter", "bland_after", "interpret", "k_pivots",
-    "n_launches"))
-def _epoch_stack(T, basis, it, status, *, ncols_price, max_iter, bland_after,
-                 interpret, k_pivots, n_launches):
-    """One epoch: up to ``n_launches`` fused K-pivot launches over the dense
-    active prefix, stopping early when every lane is done."""
-    from repro.kernels.ops import simplex_pivot  # deferred, like _phase_stack
-
-    def cond(carry):
-        _, _, it, status, launch = carry
-        return (launch < n_launches) & jnp.any(
-            (status == _RUNNING) & (it < max_iter))
-
-    def body(carry):
-        T, basis, it, status, launch = carry
-        T, basis, it, status = simplex_pivot(
-            T, basis, it, status, ncols_price=ncols_price,
-            bland_after=bland_after, max_iter=max_iter, k_pivots=k_pivots,
-            interpret=interpret,
-        )
-        return T, basis, it, status, launch + 1
-
-    T, basis, it, status, _ = lax.while_loop(
-        cond, body, (T, basis, it, status, jnp.int32(0)))
-    return T, basis, it, status
-
-
-def _phase_compact(T, basis, ncols_price, max_iter, bland_after, interpret,
-                   k_pivots, n_launches):
-    """Compaction-epoch twin of ``_phase_stack``; same contract, same bits.
-
-    Host buffers hold the full batch; between epochs, finished lanes are
-    scattered back and the survivors gathered into a dense prefix padded to
-    the next power-of-two rung (padding lanes carry status OPTIMAL, so the
-    in-kernel mask makes them identity rides).
-    """
-    B = T.shape[0]
-    Th = np.array(T)  # np.asarray of a device array is a read-only view
-    bh = np.array(basis)
-    ith = np.zeros(B, np.int32)
-    sth = np.full(B, _RUNNING, np.int32)
-    active = np.arange(B)
-
-    while active.size:
-        k = int(active.size)
-        rung = 1 << (k - 1).bit_length()  # next power of two >= k
-        Tp = np.zeros((rung,) + Th.shape[1:], Th.dtype)
-        bp = np.zeros((rung,) + bh.shape[1:], bh.dtype)
-        itp = np.zeros(rung, np.int32)
-        stp = np.full(rung, _OPTIMAL, np.int32)  # padding: masked identity
-        Tp[:k] = Th[active]
-        bp[:k] = bh[active]
-        itp[:k] = ith[active]
-        stp[:k] = sth[active]
-        To, bo, ito, sto = _epoch_stack(
-            Tp, bp, itp, stp, ncols_price=ncols_price, max_iter=max_iter,
-            bland_after=bland_after, interpret=interpret, k_pivots=k_pivots,
-            n_launches=n_launches,
-        )
-        To, bo = np.asarray(To), np.asarray(bo)
-        ito, sto = np.asarray(ito), np.asarray(sto)
-        Th[active] = To[:k]
-        bh[active] = bo[:k]
-        ith[active] = ito[:k]
-        sth[active] = sto[:k]
-        active = active[(sto[:k] == _RUNNING) & (ito[:k] < max_iter)]
-
-    sth = np.where(sth == _RUNNING, np.int32(_ITER_LIMIT), sth)
-    return Th, bh, ith, sth
-
-
-def _solve_batch_pallas_compact(lp, n, m_ub, m_eq, max_iter, interpret):
-    """Host-level compaction-epoch driver around the fused K-pivot kernel.
-
-    Setup, inter-phase bookkeeping, and extraction are the same jitted
-    vmapped pieces as the monolithic drivers; only the phase loop differs.
-    (k_pivots, n_launches) come from the per-shape autotune memo.
-    """
-    from repro.engine.autotune import pivot_schedule
-
-    m_rows = m_ub + m_eq
-    dummy = n + m_ub
-    bland_after = max(200, 4 * (m_rows + 1))
-
-    tune = pivot_schedule(m_rows + 1, dummy + 2, interpret)
-    kp, nl = tune["k_pivots"], tune["n_launches"]
-
-    T, basis, c_s, col_scale, c = _setup_batch(lp, n, m_ub, m_eq)
-    T, basis, it1, st1 = _phase_compact(
-        T, basis, dummy, max_iter, bland_after, interpret, kp, nl)
-    T, basis, infeasible, drivable = _between_batch(
-        T, basis, st1, c_s, n=n, dummy=dummy)
-    T, basis, it2, st2 = _phase_compact(
-        T, basis, dummy, max_iter, bland_after, interpret, kp, nl)
-    return _extract_batch(
-        T, basis, col_scale, c, infeasible, drivable, st1, st2, it1, it2,
-        n=n, dummy=dummy)
-
-
 def _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq):
     """Batched twin of ``core.backends._primal_violation``: demote "optimal"
     elements whose iterate violates a primal constraint beyond the
@@ -642,12 +474,12 @@ def _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq):
 
     The PR-8 campaign caught the serial dense simplex reading "optimal"
     while a port-serialization row was violated by ~0.24 under an objective
-    *better* than the true optimum; the batched and Pallas drivers run the
-    same pivot arithmetic, so the same silently-lost-pivot escape exists
-    here — and the service's replay certification alone cannot be relied on
-    to catch it (the objective undershoot can sit inside the replay
-    tolerance).  Two batched matvecs make "optimal" mean feasible on every
-    driver exit; demoted elements route to the serial rescue exactly like
+    *better* than the true optimum; the batched simplex runs the same pivot
+    arithmetic, so the same silently-lost-pivot escape exists here — and
+    the service's replay certification alone cannot be relied on to catch
+    it (the objective undershoot can sit inside the replay tolerance).  Two
+    batched matvecs make "optimal" mean feasible on every solver exit;
+    demoted elements route to the serial rescue exactly like
     any other non-optimal status.  Tolerance matches the serial check:
     ``1e-7 * max(1, max|x|)`` per element.
     """
@@ -674,34 +506,22 @@ def _demote_false_optimal(x, status, A_ub, b_ub, A_eq, b_eq):
 
 def solve_simplex_batched(
     c, A_ub=None, b_ub=None, A_eq=None, b_eq=None, max_iter: int = 20_000,
-    use_pallas: bool = False, interpret: bool | None = None,
-    compact: bool | None = None, warm_basis=None,
+    warm_basis=None,
 ) -> BatchedSimplexResult:
     """Solve a batch of LPs of identical shape.
 
     Arguments are batched along axis 0: c [B, n], A_ub [B, mu, n], b_ub
     [B, mu], A_eq [B, me, n], b_eq [B, me]; pass None for absent families.
 
-    The lanes solved cold cross to the device once and back once, whatever
-    the driver: every lane's LP goes in as one float64 buffer (``_pack_lp``),
-    the driver's one program slices it apart, and its results come back as
+    The lanes solved cold cross to the device once and back once: every
+    lane's LP goes in as one float64 buffer (``_pack_lp``), the one program
+    (``_solve_batch``) slices it apart, and its results come back as
     one float64 buffer that the host splits into the result's arrays
     (``_unpack_result``).  Values are bit-identical to passing and fetching
     each array on its own.  The
     crossings are counted in ``repro_simplex_transfers_total`` and their
     bytes in ``repro_simplex_transfer_bytes_total``, both by ``direction``
     (``to_device``, ``to_host``); a call served wholly warm makes none.
-
-    ``use_pallas=True`` runs both pivot phases through the fused K-pivot
-    Pallas kernel (repro.kernels.simplex_pivot) over the stacked tableaux;
-    results are identical (parity-tested) — setup, inter-phase bookkeeping,
-    and extraction are shared code.  ``compact`` selects the
-    compaction-epoch driver (default: on for batches of >= 2 — finished
-    lanes retire between epochs instead of riding every launch masked;
-    ``compact=False`` forces the monolithic masked driver, kept as the
-    parity reference).  ``interpret`` follows the kernels' usual gate
-    (None = interpret off-TPU).  LPs with no constraint rows keep the
-    vmapped path (an empty tableau has nothing to fuse).
 
     ``warm_basis`` ([B, m_rows] int, ``-1``-filled rows meaning "no seed")
     enables the basis-seeded entry: elements whose carried basis is entirely
@@ -710,7 +530,7 @@ def solve_simplex_batched(
     simplex exit certificate) and served at zero pivots when it holds; any
     element whose seed is rejected — no longer feasible or optimal under
     the new coefficients, or singular — falls back to the cold two-phase
-    drivers transparently.  ``result.warm_started`` records
+    solve transparently.  ``result.warm_started`` records
     which elements the warm entry actually served, and ``result.basis``
     carries every element's exit basis for the *next* replan.  Warm-start
     therefore never changes which elements solve, only how fast.
@@ -778,18 +598,7 @@ def solve_simplex_batched(
                 lp = _pack_lp(c, A_ub, b_ub, A_eq, b_eq)
                 if n_cold < B:
                     lp = lp[cold_idx]
-                if use_pallas and m_rows > 0:
-                    from repro.kernels.ops import _interp  # the kernels' TPU gate
-
-                    cc = compact
-                    if cc is None:
-                        cc = n_cold >= 2  # epochs need lanes to retire
-                    driver = (_solve_batch_pallas_compact if cc
-                              else _solve_batch_pallas)
-                    out = driver(lp, n, m_ub, m_eq, int(max_iter),
-                                 _interp(interpret))
-                else:
-                    out = _solve_batch(lp, n, m_ub, m_eq, int(max_iter))
+                out = _solve_batch(lp, n, m_ub, m_eq, int(max_iter))
             met.inc("repro_simplex_transfers_total", direction="to_device")
             met.inc("repro_simplex_transfer_bytes_total", lp.nbytes,
                     direction="to_device")

@@ -40,9 +40,12 @@ from .batched_sim import simulate_bucket
 from .batched_simplex import STATUS, solve_simplex_batched
 from .cache import CachedSolution, SolutionCache
 
-__all__ = ["solve_bulk", "BatchedBackend", "PallasBackend", "PlanService"]
+__all__ = ["solve_bulk", "BatchedBackend", "PlanService"]
 
 _REPLAY_TOL = 1e-6
+# the engine's label: a served plan's backend, and the ``path`` of its spans
+# and metrics
+_LABEL = "batched"
 
 
 def _result_from_gamma(
@@ -62,13 +65,12 @@ def _result_from_gamma(
     )
 
 
-def _replay_hits(instances, hit_idx, sols, results, label, use_pallas,
-                 cache_s, met) -> None:
+def _replay_hits(instances, hit_idx, sols, results, cache_s, met) -> None:
     """Re-materialize cached gammas through the batched ASAP replay.
 
     Hits used to call the serial ``simulate(inst, gamma)`` loop one instance
     at a time; packing them into (ladder-padded) arena buckets and replaying
-    each bucket in one vmapped/Pallas ``simulate_bucket`` launch keeps a
+    each bucket in one vmapped ``simulate_bucket`` launch keeps a
     warm-cache ``solve_bulk`` out of per-instance Python entirely.  Every
     hit gets the full v2 telemetry shape (stages/bucket/lp + ``cache_hit``)
     so :meth:`PlanArtifact.diff` works across hit/miss pairs.
@@ -80,8 +82,7 @@ def _replay_hits(instances, hit_idx, sols, results, label, use_pallas,
         for bucket in arena.buckets:
             g = bucket.gamma_padded(
                 [sols[hit_idx[j]].gamma for j in bucket.indices])
-            cs, ce, ps, pe, rs, re, mk = simulate_bucket(
-                bucket, g, use_pallas=use_pallas)
+            cs, ce, ps, pe, rs, re, mk = simulate_bucket(bucket, g)
             if rs is not None:
                 rs, re = bucket.unpad(rs), bucket.unpad(re)
             cs, ce = bucket.unpad(cs), bucket.unpad(ce)
@@ -105,12 +106,12 @@ def _replay_hits(instances, hit_idx, sols, results, label, use_pallas,
                 )
                 results[gi] = _result_from_gamma(
                     bucket.instances[b], sol.gamma, sol.lp_makespan,
-                    label + "+cache", sched=sched,
+                    _LABEL + "+cache", sched=sched,
                 )
                 telem_slots.append((gi, bucket_info))
     replay_s = time.perf_counter() - t0
     met.observe("repro_engine_stage_seconds", replay_s,
-                stage="hit_replay", path=label)
+                stage="hit_replay", path=_LABEL)
     for gi, bucket_info in telem_slots:
         # cached solutions are only ever optimal certified gammas; their
         # pivot counts were spent (and recorded) at miss time
@@ -129,7 +130,6 @@ def solve_bulk(
     cache: SolutionCache | None = None,
     fallback: bool = True,
     validate: bool = True,
-    use_pallas: bool = False,
     warm_starts: list | None = None,
     devices: list | None = None,
     n_shards: int | None = None,
@@ -140,11 +140,6 @@ def solve_bulk(
     objectives delegate to the serial solver per instance.  ``validate``
     is forwarded to the serial solver on the (rare) uncertified-element
     fallback — the batched path itself always certifies by replay.
-
-    ``use_pallas=True`` routes the simplex pivots and the ASAP replay
-    through the fused Pallas kernels (repro.kernels.simplex_pivot /
-    asap_replay); results and statuses are parity-identical to the vmapped
-    path, only the reported ``backend`` label changes to ``"pallas"``.
 
     ``warm_starts`` (optional, parallel to ``instances``) carries per-
     instance exit bases from a previous solve of a perturbed sibling; rows
@@ -159,7 +154,6 @@ def solve_bulk(
     deterministic assignment, parity-locked results; both ``None`` (the
     default) keeps the single-device path below.
     """
-    label = "pallas" if use_pallas else "batched"
     if objective != "makespan":
         return [solve(inst, objective=objective, validate=validate) for inst in instances]
     if devices is not None or n_shards is not None:
@@ -167,13 +161,13 @@ def solve_bulk(
 
         return solve_bulk_sharded(
             instances, objective=objective, cache=cache, fallback=fallback,
-            validate=validate, use_pallas=use_pallas, warm_starts=warm_starts,
+            validate=validate, warm_starts=warm_starts,
             devices=devices, n_shards=n_shards,
         )
 
     met = obs_metrics.get_registry()
-    met.inc("repro_engine_bulk_solves_total", path=label)
-    with span("engine.solve_bulk", n=len(instances), path=label):
+    met.inc("repro_engine_bulk_solves_total", path=_LABEL)
+    with span("engine.solve_bulk", n=len(instances), path=_LABEL):
         n = len(instances)
         results: list = [None] * n
         t0 = time.perf_counter()
@@ -190,8 +184,7 @@ def solve_bulk(
             hit_idx = [i for i in range(n) if sols[i] is not None]
         cache_s = time.perf_counter() - t0
         if hit_idx:
-            _replay_hits(instances, hit_idx, sols, results, label,
-                         use_pallas, cache_s, met)
+            _replay_hits(instances, hit_idx, sols, results, cache_s, met)
         if not pending:
             return results
 
@@ -202,14 +195,14 @@ def solve_bulk(
 
         for bucket in arena.buckets:
             _solve_bucket(bucket, instances, results, keys, pending, cache,
-                          label, use_pallas, fallback, validate, met,
+                          fallback, validate, met,
                           {"cache_lookup_s": cache_s, "pack_s": pack_s},
                           warm_starts)
     return results
 
 
-def _solve_bucket(bucket, instances, results, keys, pending, cache, label,
-                  use_pallas, fallback, validate, met, shared_stages,
+def _solve_bucket(bucket, instances, results, keys, pending, cache,
+                  fallback, validate, met, shared_stages,
                   warm_starts=None) -> None:
     """Solve one packed bucket in place: LP build -> batched simplex ->
     batched ASAP replay -> certify-or-rescue, with per-stage timings and
@@ -234,18 +227,18 @@ def _solve_bucket(bucket, instances, results, keys, pending, cache, label,
         t0 = time.perf_counter()
         with span("engine.simplex", B=B, rows=len(lp.b_ub) + len(lp.b_eq)):
             res = solve_simplex_batched(c, lp.A_ub, lp.b_ub, lp.A_eq, lp.b_eq,
-                                        use_pallas=use_pallas, warm_basis=wb)
+                                        warm_basis=wb)
         simplex_s = time.perf_counter() - t0
         if wb is not None:
             met.inc("repro_simplex_warm_starts_total",
-                    int(res.warm_started.sum()), path=label)
+                    int(res.warm_started.sum()), path=_LABEL)
         met.inc("repro_simplex_pivots_total",
-                int(res.iterations_phase1.sum()), phase="1", path=label)
+                int(res.iterations_phase1.sum()), phase="1", path=_LABEL)
         met.inc("repro_simplex_pivots_total",
-                int(res.iterations_phase2.sum()), phase="2", path=label)
+                int(res.iterations_phase2.sum()), phase="2", path=_LABEL)
         for code, count in zip(*np.unique(res.status, return_counts=True)):
             met.inc("repro_simplex_status_total", int(count),
-                    status=STATUS[int(code)], path=label)
+                    status=STATUS[int(code)], path=_LABEL)
 
         gammas = lp.gamma_of(res.x)
         lp_mks = lp.makespan_of(res.x)
@@ -255,7 +248,7 @@ def _solve_bucket(bucket, instances, results, keys, pending, cache, label,
         t0 = time.perf_counter()
         with span("engine.replay", B=B):
             cs, ce, ps, pe, rs, re, mk = simulate_bucket(
-                bucket, bucket.gamma_padded(list(gammas)), use_pallas=use_pallas)
+                bucket, bucket.gamma_padded(list(gammas)))
         replay_s = time.perf_counter() - t0
 
         stages = dict(shared_stages, lp_build_s=lp_build_s,
@@ -299,7 +292,7 @@ def _solve_bucket(bucket, instances, results, keys, pending, cache, label,
                         f"batched solve failed for instance {gi}: "
                         f"status={res.status_str(b)} replay={mk[b]} lp={lp_mks[b]}"
                     )
-                met.inc("repro_engine_fallback_total", path=label,
+                met.inc("repro_engine_fallback_total", path=_LABEL,
                         reason=res.status_str(b))
                 t0 = time.perf_counter()
                 with span("engine.serial_rescue", index=gi,
@@ -332,20 +325,20 @@ def _solve_bucket(bucket, instances, results, keys, pending, cache, label,
                 ret_end=re[b] if re is not None else None,
             )
             results[gi] = _result_from_gamma(
-                inst, gammas[b], lp_mks[b], label, sched=sched
+                inst, gammas[b], lp_mks[b], _LABEL, sched=sched
             )
             results[gi].telemetry = telem(b)
             if cache is not None:
                 cache.put(keys[gi], CachedSolution(
-                    gamma=gammas[b], lp_makespan=float(lp_mks[b]), backend=label
+                    gamma=gammas[b], lp_makespan=float(lp_mks[b]), backend=_LABEL
                 ))
     bucket_s = time.perf_counter() - bucket_t0
     met.observe("repro_engine_bucket_solve_seconds", bucket_s,
                 topology=bucket.topology, m=bucket.m_real, T=bucket.T_real,
-                q=q_label, path=label)
+                q=q_label, path=_LABEL)
     for stage, dt in (("lp_build", lp_build_s), ("simplex", simplex_s),
                       ("replay", replay_s)):
-        met.observe("repro_engine_stage_seconds", dt, stage=stage, path=label)
+        met.observe("repro_engine_stage_seconds", dt, stage=stage, path=_LABEL)
 
 
 class BatchedBackend(SolverBackend):
@@ -360,7 +353,6 @@ class BatchedBackend(SolverBackend):
     """
 
     name = "batched"
-    use_pallas = False  # subclass hook: route through the fused Pallas kernels
 
     def __init__(self, cache: SolutionCache | None = None, fallback: bool = True,
                  devices: list | None = None, n_shards: int | None = None):
@@ -407,7 +399,6 @@ class BatchedBackend(SolverBackend):
                 cache=self.cache,
                 fallback=self.fallback,
                 validate=validate,
-                use_pallas=self.use_pallas,
                 warm_starts=warm if any(w is not None for w in warm) else None,
                 devices=self.devices,
                 n_shards=self.n_shards,
@@ -418,35 +409,6 @@ class BatchedBackend(SolverBackend):
             if reports[i] is None:
                 reports[i] = get_backend("auto").solve(req)
         return reports
-
-
-class PallasBackend(BatchedBackend):
-    """The batched engine with its hot loops in fused Pallas kernels.
-
-    Same bulk path, cache semantics, certification-by-replay, and serial
-    fallback contract as :class:`BatchedBackend` — the simplex pivots and
-    the ASAP replay just run in ``repro.kernels.simplex_pivot`` /
-    ``asap_replay`` (interpret-mode on the CPU).  Statuses and every
-    :class:`SolveReport` field behave identically; ``report.backend`` says
-    ``"pallas"``.  Where the kernels cannot run (probed once via
-    ``scheduling_kernels_error``) construction raises with the lowering's or
-    compiler's reason: selecting ``pallas`` never silently runs ``batched``.
-    """
-
-    name = "pallas"
-    use_pallas = True
-
-    def __init__(self, cache: SolutionCache | None = None, fallback: bool = True):
-        super().__init__(cache=cache, fallback=fallback)
-        from repro.kernels.ops import scheduling_kernels_error
-
-        reason = scheduling_kernels_error()
-        if reason is not None:
-            import jax
-
-            raise RuntimeError(
-                f"the pallas backend cannot run on {jax.default_backend()}: "
-                f"{reason}")
 
 
 @dataclasses.dataclass
@@ -486,9 +448,9 @@ class PlanService:
             DeprecationWarning,
             stacklevel=2,
         )
-        if backend not in ("batched", "pallas"):
+        if backend != "batched":
             raise ValueError(
-                f"PlanService fronts the engine backends ('batched', 'pallas'); got {backend!r}"
+                f"PlanService fronts the engine backend 'batched'; got {backend!r}"
             )
         from repro.api import Policy, Session
 

@@ -1,4 +1,7 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernels for the model side's compute hot-spots.
+
+The planner runs none of them: its device programs are the vmapped
+simplex and replay of :mod:`repro.engine`.
 
 Each kernel ships three layers:
   <name>.py  — pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target);
@@ -6,8 +9,9 @@ Each kernel ships three layers:
                interpret=True auto-fallback off-TPU);
   ref.py     — pure-jnp oracle, the allclose target for the test sweeps.
 
-Kernels: flash_attention (prefill), decode_attention (split-KV flash
-decoding), ssd_scan (Mamba-2 SSD chunked scan), rmsnorm (fused norm).
+Model kernels: flash_attention (prefill), decode_attention (split-KV
+flash decoding), ssd_scan (Mamba-2 SSD chunked scan), rmsnorm (fused
+norm).
 """
 
 from . import ops, ref

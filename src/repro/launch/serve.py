@@ -58,8 +58,7 @@ def main(argv=None):
     ap.add_argument("--plan-backend", default="batched",
                     help="solver-backend registry entry for --plan and for "
                          "the --serve plan server's policy (see "
-                         "repro.core.available_backends()); 'pallas' runs "
-                         "the engine's solve/replay in fused kernels")
+                         "repro.core.available_backends())")
     ap.add_argument("--topology", default="chain", choices=("chain", "star"),
                     help="platform family for --plan: the paper's linear "
                          "chain, or a one-port master star (stage 0 holds "
@@ -251,7 +250,7 @@ def _run(args):
         # cache, and the coalescing submit queue (repro.api — DESIGN.md §7)
         from repro.api import Policy, Session
 
-        use_engine = args.plan_backend in ("batched", "pallas")
+        use_engine = args.plan_backend == "batched"
         session = Session(policy=Policy(installments=2,
                                         backend=args.plan_backend))
         planner = Planner(stages, links, topology=args.topology,

@@ -44,8 +44,7 @@ class ChainReplanner:
     :class:`repro.api.Session` (backend handles + solution cache): every
     replan — straggler drift, stage failure, or a bulk what-if sweep — is
     stated as a (Problem, Policy) pair against the ``backend`` registry
-    entry (the batched engine by default; ``"pallas"`` runs the same engine
-    with its solve/replay hot loops in fused Pallas kernels), and platform
+    entry (the batched engine by default), and platform
     states the chain has seen before replay from the session's cache
     instead of re-solving.  The topology rides on the planner
     (``Planner(topology="star")`` replans a one-port master fleet with the

@@ -143,8 +143,8 @@ class PlanServer:
                 if not hasattr(h, "devices"):
                     raise ValueError(
                         f"backend {pol.backend!r} cannot shard; devices/"
-                        "n_shards need an engine backend ('batched' or "
-                        "'pallas') in the server policy")
+                        "n_shards need the engine backend 'batched' in "
+                        "the server policy")
                 h.devices, h.n_shards = devices, n_shards
             self.sessions.append(s)
         self._workers: list = []

@@ -1,7 +1,7 @@
 """Device-sharded solve fan-out: saturate every local device from one call.
 
 ``repro.engine.service.solve_bulk`` packs a population into exact arena
-buckets and solves each bucket in one vmapped/Pallas launch — on ONE
+buckets and solves each bucket in one vmapped launch — on ONE
 device.  This module partitions that bucket list across the local JAX
 devices and runs each partition on its own device in its own thread, so a
 bulk solve saturates the host instead of leaving all but one accelerator
@@ -129,7 +129,6 @@ def solve_bulk_sharded(
     cache=None,
     fallback: bool = True,
     validate: bool = True,
-    use_pallas: bool = False,
     warm_starts: list | None = None,
     devices: list | None = None,
     n_shards: int | None = None,
@@ -144,7 +143,8 @@ def solve_bulk_sharded(
     path; the shared solution cache and the metrics registry are both
     thread-safe, so shards write concurrently without coordination.
     """
-    from repro.engine.service import _replay_hits, _solve_bucket, solve_bulk
+    from repro.engine.service import (_LABEL, _replay_hits, _solve_bucket,
+                                      solve_bulk)
 
     if devices is not None and n_shards is not None:
         if len(devices) != n_shards:
@@ -160,16 +160,15 @@ def solve_bulk_sharded(
     if n_dev == 1 or objective != "makespan":
         return solve_bulk(
             instances, objective=objective, cache=cache, fallback=fallback,
-            validate=validate, use_pallas=use_pallas, warm_starts=warm_starts,
+            validate=validate, warm_starts=warm_starts,
         )
 
     from repro.engine.arena import InstanceArena
 
-    label = "pallas" if use_pallas else "batched"
     met = obs_metrics.get_registry()
-    met.inc("repro_engine_bulk_solves_total", path=label)
+    met.inc("repro_engine_bulk_solves_total", path=_LABEL)
     met.inc("repro_serve_sharded_solves_total", shards=n_dev)
-    with span("serve.shard_solve", n=len(instances), shards=n_dev, path=label):
+    with span("serve.shard_solve", n=len(instances), shards=n_dev, path=_LABEL):
         n = len(instances)
         results: list = [None] * n
         t0 = time.perf_counter()
@@ -184,8 +183,7 @@ def solve_bulk_sharded(
             hit_idx = [i for i in range(n) if sols[i] is not None]
         cache_s = time.perf_counter() - t0
         if hit_idx:
-            _replay_hits(instances, hit_idx, sols, results, label,
-                         use_pallas, cache_s, met)
+            _replay_hits(instances, hit_idx, sols, results, cache_s, met)
         if not pending:
             return results
 
@@ -213,14 +211,14 @@ def solve_bulk_sharded(
                         for bucket in buckets:
                             _solve_bucket(
                                 bucket, instances, results, keys, pending,
-                                cache, label, use_pallas, fallback, validate,
+                                cache, fallback, validate,
                                 met, shared_stages, warm_starts)
             except BaseException as e:  # surfaced after join, first wins
                 errors[i] = e
             finally:
                 met.observe("repro_serve_shard_seconds",
                             time.perf_counter() - t_dev,
-                            shard=i, path=label)
+                            shard=i, path=_LABEL)
                 met.inc("repro_serve_shard_elements_total", elems, shard=i)
 
         threads = [

@@ -27,7 +27,7 @@ def _problem(topology="chain", return_ratio=0.0):
     )
 
 
-@pytest.mark.parametrize("backend", ["simplex", "scipy", "batched", "pallas"])
+@pytest.mark.parametrize("backend", ["simplex", "scipy", "batched", "auto"])
 @pytest.mark.parametrize(
     "topology,ret", [("chain", 0.0), ("chain", 0.25), ("star", 0.0), ("star", 0.25)]
 )

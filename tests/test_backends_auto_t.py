@@ -50,6 +50,20 @@ def test_registry_names_and_resolution():
         get_backend(None)
 
 
+def test_pallas_is_not_a_backend():
+    """The engine has one solver path: a policy naming the removed
+    ``pallas`` backend fails like any unknown name, naming the ones there
+    are."""
+    import re
+
+    from repro.api import Policy, Problem, Session
+
+    assert "pallas" not in available_backends()
+    problem = Problem(w=[1.0, 2.0], z=[0.5], v_comm=[1.0], v_comp=[1.0])
+    with pytest.raises(ValueError, match=re.escape(
+            f"unknown solver backend 'pallas'; available: {available_backends()}")):
+        Session(policy=Policy(backend="pallas")).solve(problem)
+
 def test_solve_shim_reports_carry_their_request():
     rng = np.random.default_rng(0)
     inst = random_instance(rng, m=3, n_loads=2, q=1)

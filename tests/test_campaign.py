@@ -6,8 +6,8 @@ Pins the four properties the campaign is trusted for:
   and every instance re-materializes exactly from (seed, cell_id, index);
 * **classification** — hand-built cases land in each of the five classes,
   and a synthetic anomaly hard-fails via ``require_clean``;
-* **end-to-end** — a tiny campaign runs clean through every backend
-  (serial auto / batched / pallas) and the document validates;
+* **end-to-end** — a tiny campaign runs clean through the serial auto and
+  scipy backends and the batched engine, and the document validates;
 * **gating** — ``scripts/check_campaign.py`` passes on a clean document
   vs its own distilled baseline and fails on anomalies / rate drops.
 
@@ -242,7 +242,7 @@ def test_run_strategy_marks_out_of_model_instances_unsupported():
 # ------------------------------------------------------- e2e per backend
 
 
-@pytest.mark.parametrize("backend", ["auto", "batched", "pallas"])
+@pytest.mark.parametrize("backend", ["auto", "batched", "scipy"])
 def test_tiny_campaign_end_to_end(backend):
     spec = micro_spec(name=f"tiny-{backend}", backend=backend,
                       releases=(False,), comm_to_comp=(0.02,))

@@ -7,13 +7,20 @@ Covers the acceptance bar of the engine PR:
     (m=2, T=1) edge case;
   * batched simplex == core.simplex (and scipy/HiGHS when present) on
     random LPs, including infeasible/unbounded statuses;
+  * the two device programs' cores against the independent oracles of
+    ``tests/oracles.py``: the vmapped pivot loop ``_phase`` step for step
+    against ``simplex_pivot_ref``, and the replay ``_sim_batch`` against
+    ``asap_replay_ref``;
   * solve_bulk == core.solver.solve on random schedule populations,
     including release dates, availability dates, and affine latencies;
   * the solution cache replays identical results.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from oracles import asap_replay_ref, simplex_pivot_ref
 
 from repro.core.instance import Chain, Instance, Loads, random_instance
 from repro.core.simplex import solve_simplex
@@ -27,6 +34,9 @@ from repro.engine import (
     solve_bulk,
     solve_simplex_batched,
 )
+from repro.engine.batched_sim import _sim_batch
+from repro.engine.batched_simplex import _phase
+from repro.jaxenv import x64
 
 ATOL = 1e-9
 
@@ -110,6 +120,63 @@ def test_padded_bucket_masks_fake_cells():
     np.testing.assert_allclose(exact, ref, atol=ATOL, rtol=0)
 
 
+# ------------------------------------------- the replay program vs its oracle
+
+
+def _random_replay_batch(rng, B, m, T):
+    """``_sim_batch``'s arguments for B random chains: (w_cell, z, latency,
+    tau, vcomm, vcomp, rel, valid, gamma)."""
+    mk = lambda *s: jnp.abs(jnp.asarray(rng.normal(size=s)))
+    return (mk(B, m, T) + 0.1, mk(B, m - 1) + 0.1, mk(B, m - 1) * 0.01,
+            mk(B, m) * 0.1, mk(B, T) + 0.1, mk(B, T) + 0.1, mk(B, T) * 0.2,
+            jnp.ones(T), mk(B, m, T) + 0.05)
+
+
+def _sim(w_cell, z, latency, tau, vcomm, vcomp, rel, valid, gamma, retr=None,
+         topology="chain"):
+    """The served replay program; ``retr`` turns the return phase on."""
+    with_ret = retr is not None
+    if retr is None:
+        retr = jnp.zeros_like(vcomm)  # read only when the return phase is on
+    return _sim_batch(w_cell, z, latency, tau, vcomm, vcomp, rel, retr, valid,
+                      gamma, topology, with_ret)
+
+
+@pytest.mark.parametrize("B,m,T,topology,with_ret", [
+    (1, 2, 1, "chain", False), (3, 4, 5, "chain", False),
+    (2, 6, 8, "chain", False), (3, 4, 5, "chain", True),
+    (3, 4, 5, "star", False), (3, 4, 5, "star", True)])
+def test_sim_batch_matches_replay_oracle(B, m, T, topology, with_ret):
+    rng = np.random.default_rng(2)
+    with x64():
+        args = _random_replay_batch(rng, B, m, T)
+        retr = jnp.abs(jnp.asarray(rng.normal(size=(B, T)))) * 0.5
+        retr = retr if with_ret else None
+        out = _sim(*args, retr=retr, topology=topology)
+        want = asap_replay_ref(*args, retr=retr, topology=topology)
+        names = ("cs", "ce", "ps", "pe") + (("rs", "re") if with_ret else ())
+        assert len(out) == len(want) == len(names) + 1
+        for got, exp, name in zip(out, want, names + ("mk",)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(exp), rtol=0, atol=1e-12,
+                err_msg=name)
+
+
+def test_sim_batch_masks_padded_cells():
+    rng = np.random.default_rng(3)
+    with x64():
+        args = list(_random_replay_batch(rng, 2, 3, 6))
+        valid = jnp.asarray([1.0, 1.0, 1.0, 1.0, 0.0, 0.0])
+        # padded trailing cells: zero volumes/releases, latency masked by valid
+        for i in (4, 5, 6):  # vcomm, vcomp, rel
+            args[i] = args[i].at[:, 4:].set(0.0)
+        args[8] = args[8].at[:, :, 4:].set(0.0)  # gamma
+        args[7] = valid
+        cs, ce, ps, pe, mk = _sim(*args)
+        real_mk = np.max(np.asarray(pe)[:, :, 3], axis=1)
+        np.testing.assert_allclose(np.asarray(mk), real_mk, rtol=0, atol=1e-12)
+
+
 def test_arena_scatter_restores_caller_order():
     rng = np.random.default_rng(3)
     insts = _spiced_population(rng, n=12)
@@ -187,6 +254,89 @@ def test_batched_simplex_batch_axis_and_statuses():
     assert list(res.status) == [0, 1, 2]
     assert res.objective[0] == pytest.approx(3.0, abs=1e-9)
     assert np.isnan(res.objective[1])
+
+
+# ----------------------------------- the vmapped pivot loop vs its oracle
+
+
+def _random_tableau_stack(rng, B, R, C):
+    T = jnp.asarray(rng.normal(size=(B, R, C)))
+    T = T.at[:, :-1, -1].set(jnp.abs(T[:, :-1, -1]))  # feasible rhs
+    basis = jnp.asarray(rng.integers(0, C - 2, size=(B, R - 1)))
+    return T, basis
+
+
+def _vmapped_phase(T, basis, *, ncols_price, bland_after, max_iter):
+    """The served pivot loop, ``_phase`` under ``vmap``: at most
+    ``max_iter`` pivots on every lane."""
+    return jax.vmap(_phase, in_axes=(0, 0, None, None, None))(
+        T, basis, ncols_price, max_iter, bland_after)
+
+
+def _oracle_pivots(T, basis, k, *, ncols_price, bland_after):
+    """k steps of the element-by-element oracle from a fresh start."""
+    B = T.shape[0]
+    state = (T, basis, jnp.zeros(B, jnp.int32), jnp.full(B, -1, jnp.int32))
+    for _ in range(k):
+        state = simplex_pivot_ref(*state, ncols_price=ncols_price,
+                                  bland_after=bland_after, max_iter=50)
+    return state
+
+
+def _assert_phase_matches_oracle(got, want):
+    T, basis, it, status = (np.asarray(a) for a in got)
+    wT, wbasis, wit, wstatus = (np.asarray(a) for a in want)
+    np.testing.assert_array_equal(basis, wbasis)
+    np.testing.assert_array_equal(it, wit)
+    # the loop's iteration-limit exit is the oracle's "still running"
+    np.testing.assert_array_equal(np.where(status == 3, -1, status), wstatus)
+    np.testing.assert_allclose(T, wT, rtol=1e-12)
+
+
+@pytest.mark.parametrize("B,R,C", [(1, 2, 4), (4, 5, 8), (3, 7, 12)])
+def test_vmapped_phase_matches_pivot_oracle(B, R, C):
+    rng = np.random.default_rng(0)
+    kw = dict(ncols_price=C - 2, bland_after=100)
+    with x64():
+        T, basis = _random_tableau_stack(rng, B, R, C)
+        for k in (1, 2, 3):  # pivots compound: the oracle must track them
+            _assert_phase_matches_oracle(
+                _vmapped_phase(T, basis, max_iter=k, **kw),
+                _oracle_pivots(T, basis, k, **kw))
+
+
+def test_vmapped_phase_leaves_finished_lane_untouched():
+    rng = np.random.default_rng(1)
+    with x64():
+        T, basis = _random_tableau_stack(rng, 3, 4, 7)
+        T = T.at[1, -1, :5].set(jnp.abs(T[1, -1, :5]))  # lane 1: optimal
+        T = T.at[0, -1, 0].set(-1.0).at[2, -1, 0].set(-1.0)  # the rest price in
+        out = _vmapped_phase(T, basis, ncols_price=5, bland_after=100,
+                             max_iter=3)
+        np.testing.assert_array_equal(np.asarray(out[0])[1], np.asarray(T)[1])
+        np.testing.assert_array_equal(np.asarray(out[1])[1], np.asarray(basis)[1])
+        assert np.asarray(out[2]).tolist()[1] == 0  # no pivot
+        assert int(out[3][1]) == 0  # optimal at entry, optimal at exit
+        assert int(out[2][0]) > 0 and int(out[2][2]) > 0  # its neighbours pivot
+
+
+@pytest.mark.parametrize("bland_after,want_row", [(100, 2), (0, 1)])
+def test_vmapped_phase_harris_ratio_test(bland_after, want_row):
+    """Column 0 enters.  Row 0's entry is above the absolute pivot threshold
+    but below the relative one (an exact min-ratio rule would take it at
+    ratio 0); rows 1 and 2 tie within Harris's step, where Dantzig takes
+    the larger pivot (row 2) and Bland the smaller basis index (row 1)."""
+    kw = dict(ncols_price=2, bland_after=bland_after)
+    with x64():
+        T = jnp.asarray([[[2e-9, 1.0, 0.0, 0.0],
+                          [2.0, 0.0, 0.0, 1.0],
+                          [4.0, 1.0, 0.0, 2.0 + 1e-10],
+                          [-1.0, 0.0, 0.0, 0.0]]])
+        basis = jnp.asarray([[5, 6, 7]], jnp.int32)
+        out = _vmapped_phase(T, basis, max_iter=1, **kw)
+        _assert_phase_matches_oracle(out, _oracle_pivots(T, basis, 1, **kw))
+        assert np.asarray(out[1])[0].tolist() == [
+            0 if r == want_row else b for r, b in enumerate([5, 6, 7])]
 
 
 # ----------------------------------------------------------------- solve_bulk

@@ -418,22 +418,6 @@ def test_serial_rescue_structured_event(registry, monkeypatch):
     assert registry.value("repro_session_events_total", kind="serial-rescue") == 1.0
 
 
-def test_pallas_degrade_structured_event(registry, monkeypatch):
-    """Where the fused kernels cannot run, 'pallas' no longer degrades to
-    the plain batched path: selecting it raises with the probe's reason,
-    and nothing is served or recorded as a degrade."""
-    import repro.kernels.ops as kops
-    from repro.api import Policy, Session
-
-    monkeypatch.setattr(kops, "scheduling_kernels_error",
-                        lambda: "NotImplementedError: refused by the lowering")
-    s = Session(policy=Policy(backend="pallas", installments=2))
-    with pytest.raises(RuntimeError, match="refused by the lowering"):
-        s.solve(_chain_problem())
-    assert not any("degrade" in k for k in registry.snapshot())
-    assert registry.value("repro_session_events_total", kind="degrade") == 0.0
-
-
 def test_error_artifact_preserves_class_and_truncates_at_word(registry):
     """The error path keeps the exception class out of the truncation's way
     and never cuts mid-word (the historical [:200] did both)."""

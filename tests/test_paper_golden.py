@@ -47,7 +47,7 @@ def test_motivating_example_q2_hand_schedule():
     assert abs(lp.makespan - GOLDEN_Q2) <= 1e-9
 
 
-@pytest.mark.parametrize("backend", ["simplex", "batched", "pallas"])
+@pytest.mark.parametrize("backend", ["simplex", "batched", "auto"])
 def test_motivating_example_same_golden_on_every_backend(backend):
     from repro.core.backends import SolveRequest, get_backend
 

@@ -1,13 +1,13 @@
-"""Differential scheduling fuzz: serial simplex == batched == pallas,
-over every scenario axis the IR emits.
+"""Differential scheduling fuzz: serial simplex == batched, over every
+scenario axis the IR emits.
 
 The paper's claim that the LP dominates the heuristics is only as good as
-the solver, and the engine now has three implementations of it (NumPy
-reference, vmapped jnp, fused Pallas kernels).  This suite generates random
+the solver, and the repository has two implementations of it (the NumPy
+reference and the vmapped engine).  This suite generates random
 platforms — topology ∈ {chain, star}, heterogeneous ``w``/``z``/``tau``,
 release dates, affine latencies (the (2b)/(3b) own-port rows / the star's
 one-port master rows), result-return ratios ∈ {0, >0}, ``q`` = 1..4,
-``m`` = 2..8 — and asserts all three agree on makespans at <= 1e-9 *and* on
+``m`` = 2..8 — and asserts both agree on makespans at <= 1e-9 *and* on
 status codes.  Schedule LPs are feasible by construction on both topologies,
 so the infeasible / unbounded / degenerate status parity is pinned on raw
 LP stacks below (those paths are topology-independent: the batched simplex
@@ -26,8 +26,7 @@ import pytest
 from repro.core.backends import SolveRequest, get_backend
 from repro.core.instance import Chain, Instance, Loads, Star
 from repro.core.simplex import solve_simplex
-from repro.core.simulator import simulate
-from repro.engine import makespans, solve_bulk
+from repro.engine import solve_bulk
 from repro.engine.batched_simplex import STATUS, solve_simplex_batched
 
 RTOL = 1e-9
@@ -40,7 +39,7 @@ try:
 except ImportError:  # pragma: no cover - CI installs hypothesis
     HAVE_HYPOTHESIS = False
 
-# (m, n_loads, q) — bounded so the three backends compile a fixed set of
+# (m, n_loads, q) — bounded so the engine compiles a fixed set of
 # shapes; spans the smallest legal platform up to the §6 protocol's m=8
 SHAPES = [(2, 1, 1), (2, 2, 2), (3, 2, 1), (4, 1, 3), (5, 2, 2),
           (6, 1, 4), (8, 2, 1)]
@@ -67,21 +66,15 @@ def random_platform_instance(rng, m, n_loads, q, with_latency, with_release,
     )
 
 
-def assert_three_way_parity(inst: Instance) -> None:
+def assert_two_way_parity(inst: Instance) -> None:
     req = SolveRequest(instance=inst)
     rs = get_backend("simplex").solve(req)
     rb = get_backend("batched").solve(req)
-    rp = get_backend("pallas").solve(req)
     # statuses must agree; schedule LPs are always feasible, so this is
-    # "optimal" three ways (a backend-specific non-optimal would diverge here)
-    assert rs.status == rb.status == rp.status == "optimal", (
-        rs.status, rb.status, rp.status)
+    # "optimal" both ways (a backend-specific non-optimal would diverge here)
+    assert rs.status == rb.status == "optimal", (rs.status, rb.status)
     scale = max(abs(rs.makespan), 1.0)
     assert abs(rb.makespan - rs.makespan) <= RTOL * scale
-    assert abs(rp.makespan - rs.makespan) <= RTOL * scale
-    # pallas and batched run pivot-identical algorithms: same decisions
-    np.testing.assert_array_equal(rp.schedule.gamma, rb.schedule.gamma)
-    assert rp.backend in ("pallas", rb.backend)  # serial fallback matches
 
 
 def _fuzz_case(shape_idx, with_latency, with_release, with_tau, seed,
@@ -91,7 +84,7 @@ def _fuzz_case(shape_idx, with_latency, with_release, with_tau, seed,
     inst = random_platform_instance(
         rng, m, n_loads, q, with_latency, with_release, with_tau,
         topology=topology, with_returns=with_returns)
-    assert_three_way_parity(inst)
+    assert_two_way_parity(inst)
 
 
 # ------------------------------------------------------------- feasible fuzz
@@ -126,10 +119,9 @@ if HAVE_HYPOTHESIS:
 
 
 def test_bulk_three_way_mixed_population():
-    # one solve_bulk call per engine path over a mixed-shape population —
-    # now spanning both topologies and the return phase in the same call,
-    # exercising the (topology, returns, m, T, q) bucketing + the
-    # batched<->pallas label/caching plumbing
+    # one solve_bulk call over a mixed-shape population — spanning both
+    # topologies and the return phase in the same call, exercising the
+    # (topology, returns, m, T, q) bucketing — against serial solves
     rng = np.random.default_rng(7)
     insts = []
     for k, (m, n_loads, q) in enumerate(SHAPES[:4]):
@@ -138,39 +130,11 @@ def test_bulk_three_way_mixed_population():
                 rng, m, n_loads, q, bool(k % 2), bool(k % 2 == 0), False,
                 topology=topology, with_returns=bool(k % 2)))
     rb = solve_bulk(insts)
-    rp = solve_bulk(insts, use_pallas=True)
-    for inst, b, p in zip(insts, rb, rp):
-        assert b.status == p.status == "optimal"
-        assert abs(b.makespan - p.makespan) <= RTOL * max(abs(b.makespan), 1.0)
+    for inst, b in zip(insts, rb):
+        assert b.status == "optimal"
+        assert b.backend == "batched"
         rs = get_backend("simplex").solve(SolveRequest(instance=inst))
-        assert abs(p.makespan - rs.makespan) <= RTOL * max(abs(rs.makespan), 1.0)
-
-
-def test_replay_kernel_parity_padded_and_exact():
-    # the ASAP-replay kernel against the NumPy simulator on random
-    # fractions, both exact buckets and ladder-padded ones (in-kernel
-    # masking of fake cells/processors, forward and return phases alike),
-    # across both topologies
-    rng = np.random.default_rng(11)
-    insts, gammas = [], []
-    for topology in TOPOLOGIES:
-        for with_ret, (m, n_loads, q) in zip(
-                (False, True, True, False),
-                [(3, 2, 1), (3, 2, 1), (5, 2, 2), (6, 1, 4)]):
-            inst = random_platform_instance(
-                rng, m, n_loads, q, True, True, True,
-                topology=topology, with_returns=with_ret)
-            g = np.abs(rng.normal(size=(inst.m, inst.total_installments))) + 0.1
-            cells = list(inst.cells())
-            for n in range(inst.N):
-                cols = [t for t, (load, _) in enumerate(cells) if load == n]
-                g[:, cols] /= g[:, cols].sum()
-            insts.append(inst)
-            gammas.append(g)
-    ref = [simulate(i, g).makespan for i, g in zip(insts, gammas)]
-    for pad in (False, True):
-        got = makespans(insts, gammas, pad_shapes=pad, use_pallas=True)
-        np.testing.assert_allclose(got, ref, rtol=0, atol=RTOL)
+        assert abs(b.makespan - rs.makespan) <= RTOL * max(abs(rs.makespan), 1.0)
 
 
 # -------------------------------------------- non-optimal status parity
@@ -181,11 +145,9 @@ def test_infeasible_status_parity():
     c = np.array([[1.0]])
     A_ub, b_ub = np.array([[[1.0]]]), np.array([[-1.0]])
     rb = solve_simplex_batched(c, A_ub, b_ub)
-    rp = solve_simplex_batched(c, A_ub, b_ub, use_pallas=True)
     ref = solve_simplex(c[0], A_ub[0], b_ub[0])
-    assert STATUS[int(rb.status[0])] == STATUS[int(rp.status[0])] \
-        == ref.status == "infeasible"
-    assert np.isnan(rb.objective[0]) and np.isnan(rp.objective[0])
+    assert STATUS[int(rb.status[0])] == ref.status == "infeasible"
+    assert np.isnan(rb.objective[0])
 
 
 def test_unbounded_status_parity():
@@ -193,31 +155,28 @@ def test_unbounded_status_parity():
     c = np.array([[-1.0]])
     A_ub, b_ub = np.array([[[-1.0]]]), np.array([[1.0]])
     rb = solve_simplex_batched(c, A_ub, b_ub)
-    rp = solve_simplex_batched(c, A_ub, b_ub, use_pallas=True)
     ref = solve_simplex(c[0], A_ub[0], b_ub[0])
-    assert STATUS[int(rb.status[0])] == STATUS[int(rp.status[0])] \
-        == ref.status == "unbounded"
+    assert STATUS[int(rb.status[0])] == ref.status == "unbounded"
 
 
 def test_degenerate_status_parity():
     # -x - y = 0 with x, y >= 0: phase 1 is immediately optimal with the
     # artificial still basic at zero level on a row with nonzero entries —
-    # the batched paths flag status 4 (serial-fallback material) while the
+    # the batched path flags status 4 (serial-fallback material) while the
     # NumPy solver pays the drive-out pivots and solves it
     c = np.array([[1.0, 1.0]])
     A_eq, b_eq = np.array([[[-1.0, -1.0]]]), np.array([[0.0]])
     rb = solve_simplex_batched(c, A_eq=A_eq, b_eq=b_eq)
-    rp = solve_simplex_batched(c, A_eq=A_eq, b_eq=b_eq, use_pallas=True)
-    assert int(rb.status[0]) == int(rp.status[0]) == 4
+    assert int(rb.status[0]) == 4
     assert STATUS[4] == "degenerate"
-    assert np.isnan(rb.x[0]).all() and np.isnan(rp.x[0]).all()
+    assert np.isnan(rb.x[0]).all()
     ref = solve_simplex(c[0], A_eq=A_eq[0], b_eq=b_eq[0])
     assert ref.status == "optimal" and abs(ref.objective) <= 1e-12
 
 
 def test_mixed_status_batch_parity():
     # a random stack that lands a mix of optimal/infeasible/unbounded in one
-    # batch: the two engine paths must agree elementwise with the reference
+    # batch: the engine must agree elementwise with the reference
     rng = np.random.default_rng(42)
     B, n, mu, me = 8, 6, 5, 2
     c = rng.normal(size=(B, n))
@@ -226,17 +185,13 @@ def test_mixed_status_batch_parity():
     A_eq = rng.normal(size=(B, me, n))
     b_eq = rng.uniform(-1, 1, size=(B, me))
     rb = solve_simplex_batched(c, A_ub, b_ub, A_eq, b_eq)
-    rp = solve_simplex_batched(c, A_ub, b_ub, A_eq, b_eq, use_pallas=True)
-    np.testing.assert_array_equal(rb.status, rp.status)
-    np.testing.assert_array_equal(rb.iterations, rp.iterations)
     assert len(set(rb.status.tolist())) >= 2, "seed chosen to mix statuses"
     for b in range(B):
         ref = solve_simplex(c[b], A_ub[b], b_ub[b], A_eq[b], b_eq[b])
-        assert STATUS[int(rp.status[b])] == ref.status
+        assert STATUS[int(rb.status[b])] == ref.status
         if ref.status == "optimal":
             scale = max(abs(ref.objective), 1.0)
-            assert abs(rp.objective[b] - ref.objective) <= 1e-9 * scale
-            np.testing.assert_array_equal(rp.x[b], rb.x[b])
+            assert abs(rb.objective[b] - ref.objective) <= 1e-9 * scale
 
 
 # -------------------------------------------- degenerate-element routing
@@ -245,10 +200,10 @@ def test_mixed_status_batch_parity():
 @pytest.mark.parametrize("topology", TOPOLOGIES)
 def test_status4_routes_to_serial_identically(monkeypatch, topology):
     # the satellite contract: a degenerate (status-4) element must reach the
-    # serial fallback through the pallas backend exactly as through the
-    # batched one, on either topology.  Degenerate corners essentially never
-    # occur on schedule LPs, so force the flag at the solver seam and
-    # compare the full fallout.
+    # serial fallback through the batched backend and come back exactly as
+    # the serial solver plans it, on either topology.  Degenerate corners
+    # essentially never occur on schedule LPs, so force the flag at the
+    # solver seam and compare the full fallout.
     import repro.engine.service as service
 
     real = service.solve_simplex_batched
@@ -256,7 +211,7 @@ def test_status4_routes_to_serial_identically(monkeypatch, topology):
 
     def forced(*args, **kwargs):
         res = real(*args, **kwargs)
-        seen.append(kwargs.get("use_pallas", False))
+        seen.append(res.status.shape[0])
         res.status = np.full_like(np.asarray(res.status), 4)
         res.x = np.full_like(np.asarray(res.x), np.nan)
         return res
@@ -265,16 +220,17 @@ def test_status4_routes_to_serial_identically(monkeypatch, topology):
     rng = np.random.default_rng(3)
     inst = random_platform_instance(rng, 3, 2, 2, True, False, False,
                                     topology=topology, with_returns=True)
-    from repro.engine.service import BatchedBackend, PallasBackend
+    from repro.core.solver import solve
+    from repro.engine.service import BatchedBackend
 
     rb = BatchedBackend().solve(SolveRequest(instance=inst))
-    rp = PallasBackend().solve(SolveRequest(instance=inst))
-    assert seen == [False, True]  # both engines actually hit the seam
-    assert rb.status == rp.status == "optimal"
-    assert rb.backend == rp.backend  # both are the *serial* solver's label
-    assert rb.backend not in ("batched", "pallas")
-    np.testing.assert_array_equal(rp.schedule.gamma, rb.schedule.gamma)
-    assert rp.makespan == rb.makespan
+    rs = solve(inst, objective="makespan")  # the rescue's own serial solve
+    assert seen == [1]  # the engine actually hit the seam
+    assert rb.status == rs.status == "optimal"
+    assert rb.backend == rs.backend  # the *serial* solver's label
+    assert rb.backend != "batched"
+    np.testing.assert_array_equal(rs.schedule.gamma, rb.schedule.gamma)
+    assert rs.makespan == rb.makespan
 
 
 # ------------------------------------------------- campaign classifier arm
@@ -342,7 +298,7 @@ def test_false_optimal_golden_all_backends():
     # the PR-8 campaign's mis-convergence instance: the dense simplex used
     # to exit "optimal" with a port-serialization row violated by ~0.24 and
     # an objective *below* the true optimum (976.1527780792386, HiGHS).
-    # Every backend — including the batched/pallas drivers, whose exits now
+    # Every backend — including the batched engine, whose exits now
     # run the same primal-feasibility demotion — must land on the golden.
     from repro.eval import CampaignSpec, full_spec
 
@@ -351,7 +307,7 @@ def test_false_optimal_golden_all_backends():
     cell = next(c for c in spec.cells() if CampaignSpec.cell_id(c) == cell_id)
     inst = spec.materialize(cell, 0)
     golden = 976.1527780792386
-    for backend in ("simplex", "auto", "batched", "pallas"):
+    for backend in ("simplex", "auto", "batched"):
         rep = get_backend(backend).solve(SolveRequest(instance=inst))
         assert rep.status == "optimal", (backend, rep.status)
         assert abs(rep.makespan - golden) <= 1e-6 * golden, (
@@ -419,9 +375,11 @@ def _event_stream_case(topology, with_returns, warm, backend):
         assert tail["trigger"] == type(ev).__name__
         if not isinstance(ev, SpeedObserved):
             assert not tail["warm_requested"]  # structural => cold, always
-    if warm:
+    if warm and backend == "batched":
         assert any(a.events[-1]["warm"] for a in arts), \
             "warm path never engaged on coefficient events"
+    elif warm:  # the serial backends have no warm entry: every replan is cold
+        assert not any(a.events[-1]["warm"] for a in arts)
     # the equivalence: final replayed state == cold solve on a FRESH session
     cold = Session(Policy(installments=2, backend=backend)).solve(rp.problem)
     assert cold.ok
@@ -431,7 +389,7 @@ def _event_stream_case(topology, with_returns, warm, backend):
     assert abs(arts[-1].lp_makespan - cold.lp_makespan) <= RTOL * scale
 
 
-@pytest.mark.parametrize("backend", ["batched", "pallas"])
+@pytest.mark.parametrize("backend", ["batched", "auto"])
 @pytest.mark.parametrize("warm", [True, False])
 @pytest.mark.parametrize("topology,with_returns",
                          [("chain", False), ("chain", True),

@@ -57,7 +57,7 @@ def test_single_load_star_lp_matches_bus_closed_form(m, seed):
     assert abs(replay.makespan - cf) <= 1e-9 * max(abs(cf), 1.0)
 
 
-@pytest.mark.parametrize("backend", ["simplex", "batched", "pallas"])
+@pytest.mark.parametrize("backend", ["simplex", "batched", "auto"])
 def test_bus_closed_form_on_every_backend(backend):
     inst = star_bus_instance(w=[0.75, 1.5, 0.9], z=0.4)
     cf = star_single_load_makespan([0.75, 1.5, 0.9], [0.4, 0.4], 1.0, 1.0)
@@ -95,7 +95,7 @@ def _star_example(lam: float, q) -> Instance:
                     Loads(v_comm=[1.0, 1.0], v_comp=[1.0, 1.0]), q=q)
 
 
-@pytest.mark.parametrize("backend", ["simplex", "batched", "pallas"])
+@pytest.mark.parametrize("backend", ["simplex", "batched", "auto"])
 def test_one_worker_star_reproduces_chain_goldens(backend):
     b = get_backend(backend)
     r1 = b.solve(SolveRequest(instance=_star_example(0.75, q=1)))
